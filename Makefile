@@ -3,6 +3,8 @@
 
 PYTHON ?= python
 export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH))
+# tests and simulations run on the CPU; chip_smoke.py is the path to a TPU
+export JAX_PLATFORMS ?= cpu
 
 .PHONY: test coverage lenet-repro analyze bench bench-memory bench-topology bench-cluster bench-faults bench-perf doctor sentinel cluster validate lint help
 

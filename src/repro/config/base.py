@@ -223,8 +223,8 @@ class TrainConfig:
     grad_clip: float = 1.0
     accum_steps: int = 1
     seed: int = 0
-    checkpoint_every: int = 100
-    checkpoint_dir: str = "/tmp/repro_ckpt"
+    checkpoint_every: int = 100       # <= 0: no checkpoints, not even at the end
+    checkpoint_dir: str = "experiments/ckpt"
     keep_checkpoints: int = 3
     async_checkpoint: bool = True
     label_smoothing: float = 0.0

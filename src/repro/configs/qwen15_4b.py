@@ -1,4 +1,4 @@
-"""qwen1.5-4b [dense] — MHA with QKV bias.  [hf:Qwen/Qwen1.5-0.5B; hf]"""
+"""qwen1.5-4b [dense] — MHA with QKV bias.  [hf:Qwen/Qwen1.5-4B; hf]"""
 from repro.config import ArchEntry, ModelConfig, register
 
 FULL = ModelConfig(
@@ -31,6 +31,6 @@ register(ArchEntry(
     arch_id="qwen1.5-4b",
     full=FULL,
     smoke=SMOKE,
-    source="hf:Qwen/Qwen1.5-0.5B; hf",
+    source="hf:Qwen/Qwen1.5-4B; hf",
     shape_skips=(("long_500k", "pure full-attention arch: quadratic at 500k context"),),
 ))

@@ -22,7 +22,7 @@ from repro.core.debug import Divergence, compare_implementations, first_divergen
 from repro.core.engine import Engine, SimReport, SimulationCache
 from repro.core.functional import FunctionalResult, run_functional
 from repro.core.hlo_ir import SimModule, parse_hlo_module, summarize_collectives
-from repro.core.hw import CHIPS, V5E, V5P, HardwareSpec
+from repro.core.hw import CHIPS, V5E, V5P, HardwareSpec, chip_for_device_kind
 from repro.core.power import PowerReport, analyze_power
 from repro.core.sim_checkpoint import CheckpointedSim, simulate_from_checkpoint
 from repro.core.trace import to_chrome_trace, to_csv
@@ -73,7 +73,7 @@ __all__ = [
     "Simulator", "Captured", "capture", "capture_bundle", "Engine", "SimReport",
     "SimulationCache",
     "SimModule", "parse_hlo_module", "summarize_collectives", "HardwareSpec",
-    "V5E", "V5P", "CHIPS", "collective_time", "correlate", "CorrelationReport",
+    "V5E", "V5P", "CHIPS", "chip_for_device_kind", "collective_time", "correlate", "CorrelationReport",
     "first_divergence", "compare_implementations", "Divergence",
     "run_functional", "FunctionalResult", "analyze_power", "PowerReport",
     "vision_analyze", "VisionReport", "simulate_from_checkpoint",

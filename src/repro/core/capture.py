@@ -15,16 +15,6 @@ import jax
 from repro.core.hlo_ir import SimModule, parse_hlo_module, summarize_collectives
 
 
-def unwrap_cost_analysis(ca) -> Dict[str, float]:
-    """Normalize ``compiled.cost_analysis()`` across jax versions.
-
-    jax <= 0.4.x wraps the properties dict in a per-device list.
-    """
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
-
-
 @dataclass
 class Captured:
     """One captured workload: compiled executable + parsed IR + metadata."""
@@ -74,7 +64,7 @@ def capture(fn: Callable, *abstract_args, name: str = "workload",
         lowered=lowered,
         compiled=compiled,
         module=module,
-        cost_analysis=unwrap_cost_analysis(compiled.cost_analysis()),
+        cost_analysis=dict(compiled.cost_analysis() or {}),
         memory_analysis=compiled.memory_analysis(),
         capture_seconds=time.time() - t0,
         hlo_text_len=len(text),

@@ -107,3 +107,15 @@ V5P = HardwareSpec(
 )
 
 CHIPS: Dict[str, HardwareSpec] = {"tpu-v5e": V5E, "tpu-v5p": V5P}
+
+#: ``jax.Device.device_kind`` -> ``CHIPS`` key.  Code that prices a run on
+#: real hardware looks its chip up here; a kind that is missing is an error.
+DEVICE_KINDS: Dict[str, str] = {"TPU v5 lite": "tpu-v5e", "TPU v5": "tpu-v5p"}
+
+
+def chip_for_device_kind(kind: str) -> HardwareSpec:
+    """The :class:`HardwareSpec` of the chip JAX reports as ``kind``."""
+    if kind not in DEVICE_KINDS:
+        raise KeyError(f"no HardwareSpec for device kind {kind!r}; "
+                       f"known: {sorted(DEVICE_KINDS)}")
+    return CHIPS[DEVICE_KINDS[kind]]

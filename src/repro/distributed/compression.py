@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed._compat import shard_map
+from jax import shard_map
 
 
 def quantize_int8(x: jax.Array) -> Tuple[jax.Array, jax.Array]:

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed._compat import shard_map
+from jax import shard_map
 
 
 def pipeline_apply(stage_fn: Callable, stage_params: Any, x_micro: jax.Array,

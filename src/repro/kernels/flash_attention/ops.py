@@ -44,20 +44,17 @@ def flash_attention(q, k, v, causal: bool = True, window: int = 0,
 def _fwd_impl(q, k, v, causal, window, softcap, block_q, block_kv):
     b, h, s, d = q.shape
     t = k.shape[2]
+    if t % block_kv and not causal:
+        # the kernel masks padded KV positions only through the causal mask
+        raise ValueError(
+            f"non-causal flash_attention needs the KV length ({t}) to be a "
+            f"multiple of block_kv ({block_kv})")
     qp = _pad_to(q, 2, block_q)
     kp = _pad_to(k, 2, block_kv)
     vp = _pad_to(v, 2, block_kv)
-    # padded KV positions must be masked out: rely on causal/window masks for
-    # q-side pads; for kv pads add an explicit finite-length mask via window
-    # trick only when padding exists
     out = flash_attention_fwd(qp, kp, vp, causal=causal, window=window,
                               softcap=softcap, block_q=block_q,
                               block_kv=block_kv, interpret=not _on_tpu())
-    if kp.shape[2] != t and not causal:
-        # non-causal with kv padding: fall back to masked ref semantics
-        out_ref = attention_ref(q, k, v, causal=causal, window=window,
-                                softcap=softcap)
-        return out_ref
     return out[:, :, :s, :]
 
 

@@ -24,11 +24,11 @@ def conv3x3_winograd(x: jax.Array, w: jax.Array,
     j = jnp.arange(tw) * 2
     tiles = x[:, i[:, None] + jnp.arange(4)[None]]            # (b, th, 4, W', cin)
     tiles = tiles[:, :, :, j[:, None] + jnp.arange(4)[None]]  # (b, th, 4, tw, 4, cin)
-    tiles = tiles.transpose(0, 1, 3, 2, 4, 5)                  # (b, th, tw, 4, 4, cin)
+    tiles = tiles.transpose(0, 1, 2, 4, 3, 5)                  # (b, th, 4, 4, tw, cin)
 
     g = jnp.asarray(G, x.dtype)
     u = jnp.einsum("ij,jkcf,lk->ilcf", g, w.astype(x.dtype), g)  # (4,4,cin,cout)
 
     y = winograd_tiles(tiles, u, interpret=jax.default_backend() != "tpu")
-    out = y.transpose(0, 1, 3, 2, 4, 5).reshape(b, 2 * th, 2 * tw, cout)
+    out = y.transpose(0, 1, 2, 4, 3, 5).reshape(b, 2 * th, 2 * tw, cout)
     return out[:, :oh, :ow]

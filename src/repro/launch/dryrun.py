@@ -1,4 +1,7 @@
 import os
+# a CPU dry run: pin the host platform before any backend starts, so a
+# machine with an accelerator still compiles for 512 virtual host devices
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("DRYRUN_XLA_EXTRA", "") +
                            " --xla_force_host_platform_device_count=512").strip()
 
@@ -66,8 +69,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         t_compile = time.time() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    from repro.core.capture import unwrap_cost_analysis
-    cost = unwrap_cost_analysis(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     n_dev = mesh_cfg.num_devices
 
     result = {

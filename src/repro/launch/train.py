@@ -1,14 +1,22 @@
 """Production training launcher.
 
     PYTHONPATH=src python -m repro.launch.train --arch llama3-8b \
-        [--shape train_4k] [--steps N] [--smoke] [--multi-pod]
+        [--shape train_4k] [--steps N] [--smoke] [--multi-pod] [--ckpt-dir D]
 
---smoke uses the reduced config + tiny shapes on local devices (CI path);
-the full path expects a real TPU slice whose device count matches the mesh.
+--smoke uses the reduced config + tiny shapes on local devices (CI path).
+Otherwise the model runs at full width on a data-parallel (FSDP) mesh over
+the devices present; --multi-pod splits them into two pods.  Checkpoints go
+to experiments/ckpt/<arch> unless --ckpt-dir says otherwise, and a run
+resumes from the latest one there.
 """
 import argparse
+import os
+
+import jax
 
 from repro import config as C
+from repro.distributed.mesh import make_mesh_config
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.trainer import Trainer
 
 
@@ -19,9 +27,10 @@ def main():
     ap.add_argument("--steps", type=int, default=None)
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--multi-pod", action="store_true")
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
 
+    enable_compile_cache()
     entry = C.get(args.arch)
     if args.smoke:
         model = entry.smoke
@@ -31,11 +40,14 @@ def main():
     else:
         model = entry.full
         shape = C.SHAPES_BY_NAME[args.shape]
-        mesh_cfg = C.MULTI_POD_MESH if args.multi_pod else C.SINGLE_POD_MESH
+        mesh_cfg = make_mesh_config(len(jax.devices()),
+                                    pods=2 if args.multi_pod else 1)
         use_mesh = True
-    train = C.TrainConfig(total_steps=args.steps or 100,
-                          checkpoint_dir=args.ckpt_dir,
-                          accum_steps=entry.accum_steps)
+    train = C.TrainConfig(
+        total_steps=args.steps or 100,
+        checkpoint_dir=args.ckpt_dir or os.path.join("experiments", "ckpt",
+                                                     args.arch),
+        accum_steps=entry.accum_steps)
     rc = C.RunConfig(model=model, shape=shape, mesh=mesh_cfg, train=train)
     report = Trainer(rc, use_mesh=use_mesh).train()
     print(f"done: steps={report.steps_done} final_loss={report.final_loss:.4f} "

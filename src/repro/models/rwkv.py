@@ -120,7 +120,10 @@ def wkv_chunked(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
         state = lc(state, ("batch", "heads", None, None))
         return state, y
 
-    state, ys = jax.lax.scan(step, state0.astype(jnp.float32),
+    # checkpointed: the backward pass recomputes each chunk's (b, c, c, h, hd)
+    # decay instead of keeping it for every chunk (~2 GiB per layer for
+    # 2x2048 tokens at rwkv6-1.6b widths, more than a v5e can spare)
+    state, ys = jax.lax.scan(jax.checkpoint(step), state0.astype(jnp.float32),
                              (r_c, k_c, v_c, w_c))
     y = ys.swapaxes(0, 1).reshape(b, s, h, hd)
     return y.astype(r.dtype), state

@@ -1,7 +1,7 @@
 from repro.runtime.steps import (
-    StepBundle, bundle_for, decode_bundle, init_train_state, prefill_bundle,
-    train_bundle,
+    StepBundle, bundle_for, decode_bundle, init_params, init_train_state,
+    prefill_bundle, train_bundle,
 )
 
-__all__ = ["StepBundle", "bundle_for", "decode_bundle", "init_train_state",
-           "prefill_bundle", "train_bundle"]
+__all__ = ["StepBundle", "bundle_for", "decode_bundle", "init_params",
+           "init_train_state", "prefill_bundle", "train_bundle"]

@@ -36,13 +36,10 @@ class StepBundle:
     in_shardings: Optional[Tuple[Any, ...]]   # NamedShardings (None w/o mesh)
     out_shardings: Optional[Any]
     donate_argnums: Tuple[int, ...] = ()
+    compiler_options: Optional[Dict[str, str]] = None
 
     def lower(self, mesh: Optional[Mesh] = None):
-        kw = {}
-        if self.in_shardings is not None:
-            kw["in_shardings"] = self.in_shardings
-            kw["out_shardings"] = self.out_shardings
-        jitted = jax.jit(self.fn, donate_argnums=self.donate_argnums, **kw)
+        jitted = self.jit()
         if mesh is not None:
             with mesh:
                 return jitted.lower(*self.abstract_inputs)
@@ -53,7 +50,8 @@ class StepBundle:
         if self.in_shardings is not None:
             kw["in_shardings"] = self.in_shardings
             kw["out_shardings"] = self.out_shardings
-        return jax.jit(self.fn, donate_argnums=self.donate_argnums, **kw)
+        return jax.jit(self.fn, donate_argnums=self.donate_argnums,
+                       compiler_options=self.compiler_options, **kw)
 
 
 def _ambient(fn: Callable, rules, mesh, sharding=None) -> Callable:
@@ -147,7 +145,14 @@ def train_bundle(run_cfg: RunConfig, mesh: Optional[Mesh] = None) -> StepBundle:
     st_axes = state_axes(model.axes())
 
     in_sh = out_state_sh = out_sh = None
+    options = None
     if mesh is not None:
+        if (run_cfg.model.family == "ssm" and mesh.size > 1
+                and mesh.devices.flat[0].platform == "tpu"):
+            # the TPU compiler (libtpu 0.0.34) fails a scheduling RET_CHECK
+            # ("not scheduled after its control predecessor") on the sharded
+            # rwkv6 train step unless async collective fusion is off
+            options = {"xla_tpu_enable_async_collective_fusion": "false"}
         state_sh = param_shardings(st_axes, rules, mesh)
         batch_sh = jax.tree.map(
             lambda a: NamedSharding(mesh, axes_to_pspec(a, rules)), batch_axes,
@@ -156,7 +161,7 @@ def train_bundle(run_cfg: RunConfig, mesh: Optional[Mesh] = None) -> StepBundle:
         # metrics subtree: replicated (pytree-prefix sharding)
         out_sh = (state_sh, NamedSharding(mesh, P()))
     return StepBundle(fn, (state_sds, batch_sds), in_sh, out_sh,
-                      donate_argnums=(0,))
+                      donate_argnums=(0,), compiler_options=options)
 
 
 def init_train_state(run_cfg: RunConfig, key, mesh: Optional[Mesh] = None
@@ -179,6 +184,12 @@ def init_train_state(run_cfg: RunConfig, key, mesh: Optional[Mesh] = None
 # ---------------------------------------------------------------------------
 # Serve
 # ---------------------------------------------------------------------------
+
+def init_params(run_cfg: RunConfig, key) -> Any:
+    """Materialize model parameters from ``key`` on the device, in one jit."""
+    model = build_model(run_cfg.model, run_cfg.sharding)
+    return jax.jit(model.init)(key)
+
 
 def prefill_bundle(run_cfg: RunConfig, mesh: Optional[Mesh] = None) -> StepBundle:
     model = build_model(run_cfg.model, run_cfg.sharding)

@@ -37,6 +37,9 @@ class TrainReport:
     restarts: int = 0
     final_loss: float = float("nan")
     losses: List[float] = field(default_factory=list)
+    grad_norms: List[float] = field(default_factory=list)
+    #: wall seconds per step, until its state and metrics are on the device
+    step_times: List[float] = field(default_factory=list)
     slow_steps: int = 0
     checkpoints: int = 0
 
@@ -50,7 +53,6 @@ class Trainer:
         self.failure_plan = failure_plan or FailurePlan()
         self.straggler_factor = straggler_factor
         self.report = TrainReport()
-        self._step_times: List[float] = []
 
     # -- setup ---------------------------------------------------------------
     def _build(self, num_devices: Optional[int] = None):
@@ -106,8 +108,10 @@ class Trainer:
                     batch = next(data)
                     state, metrics = step_fn(state, batch)
                     self.failure_plan.check(step)
-                    loss = float(metrics["loss"])
-                    self.report.losses.append(loss)
+                    jax.block_until_ready(state)
+                    metrics = jax.device_get(metrics)
+                    self.report.losses.append(float(metrics["loss"]))
+                    self.report.grad_norms.append(float(metrics["grad_norm"]))
                     dt = time.time() - t0
                     if self.failure_plan.simulated:
                         dt += injected
@@ -116,7 +120,8 @@ class Trainer:
                         self.report.checkpoints += 1
                     self.report.steps_done += 1
                 data.close()
-                ckpt.maybe_save(total, state, force=True)
+                if rc.train.checkpoint_every > 0:
+                    ckpt.maybe_save(total, state, force=True)
                 ckpt.wait()
                 self.report.final_loss = self.report.losses[-1] if self.report.losses else float("nan")
                 return self.report
@@ -131,8 +136,8 @@ class Trainer:
                             e.step, num_devices)
 
     def _note_step_time(self, step: int, dt: float):
-        self._step_times.append(dt)
-        window = self._step_times[-21:-1]
+        self.report.step_times.append(dt)
+        window = self.report.step_times[-21:-1]
         if len(window) >= 5:
             med = statistics.median(window)
             if dt > self.straggler_factor * med:

@@ -17,9 +17,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from functools import partial
-from repro.distributed._compat import shard_map
+from jax import shard_map
+from jax.sharding import AxisType
 
-mesh = jax.make_mesh((4,), ("data",))
+mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
 
 # --- int8 compressed mean vs exact mean ---
 from repro.distributed.compression import compressed_psum_mean
@@ -37,7 +38,7 @@ print("compression_ok", err, bound)
 # --- pipeline_apply == sequential stage application ---
 from repro.distributed.pipeline import pipeline_apply
 S, M, b, d = 4, 6, 2, 8
-mesh_p = jax.make_mesh((4,), ("pod",))
+mesh_p = jax.make_mesh((4,), ("pod",), axis_types=(AxisType.Auto,))
 ws = jax.random.normal(jax.random.key(1), (S, d, d)) * 0.3
 def stage_fn(w, x):
     return jnp.tanh(x @ w)

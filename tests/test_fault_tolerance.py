@@ -37,6 +37,15 @@ def test_train_loop_loss_decreases(tmp_path):
     assert last3 < first3, f"loss did not fall: {first3} -> {last3}"
 
 
+def test_no_checkpoints_when_cadence_is_off(tmp_path):
+    rc = _tiny_run_cfg(tmp_path / "off", total=3, every=0)
+    report = Trainer(rc, use_mesh=False).train()
+    assert report.steps_done == 3 and report.checkpoints == 0
+    assert len(report.step_times) == len(report.grad_norms) == 3
+    from repro.checkpoint.store import list_steps
+    assert list_steps(str(tmp_path / "off")) == []
+
+
 def test_failure_recovery_resumes_from_checkpoint(tmp_path):
     rc = _tiny_run_cfg(tmp_path / "b", total=8, every=2)
     plan = FailurePlan(failures={4: 0})

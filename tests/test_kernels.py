@@ -25,6 +25,18 @@ def test_flash_attention_shapes(b, h, kv, s, d, causal, window):
                                rtol=2e-3, atol=2e-3)
 
 
+def test_flash_attention_refuses_unmasked_kv_padding():
+    """Non-causal attention cannot mask padded KV blocks: it raises rather
+    than answer with something other than the kernel."""
+    q = jax.random.normal(jax.random.key(1), (1, 2, 200, 32), jnp.float32)
+    with pytest.raises(ValueError, match="multiple of block_kv"):
+        flash_attention(q, q, q, False, 0, 0.0, 128, 128)
+    out = flash_attention(q, q, q, True, 0, 0.0, 128, 128)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(attention_ref(q, q, q, causal=True)),
+                               rtol=2e-3, atol=2e-3)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention_dtypes(dtype):
     b, h, kv, s, d = 1, 4, 2, 256, 64

@@ -21,6 +21,14 @@ def _capture_scan(length):
                     jax.ShapeDtypeStruct((length, 64, 64), jnp.float32))
 
 
+def test_chip_for_device_kind():
+    from repro.core import V5P, chip_for_device_kind
+    assert chip_for_device_kind("TPU v5 lite") is V5E
+    assert chip_for_device_kind("TPU v5") is V5P
+    with pytest.raises(KeyError, match="no HardwareSpec"):
+        chip_for_device_kind("TPU v6 lite")
+
+
 def test_trip_count_scaling():
     """The IR walker must scale while bodies by trip count (XLA's own
     cost_analysis does not — the reason this parser exists)."""
