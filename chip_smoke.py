@@ -13,6 +13,7 @@ a warm ``.jax_cache`` prints smaller ones.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import shutil
 import statistics
@@ -37,22 +38,26 @@ from repro.launch.serve import build_server, random_prompts  # noqa: E402
 from repro.models import build_model  # noqa: E402
 from repro.optim import abstract_state  # noqa: E402
 from repro.runtime.compile_cache import enable_compile_cache  # noqa: E402
-from repro.runtime.server import ServeStats  # noqa: E402
+from repro.runtime.server import Server, ServeStats  # noqa: E402
 from repro.runtime.steps import train_bundle  # noqa: E402
 from repro.runtime.trainer import Trainer  # noqa: E402
 
 OUT_DIR = ROOT / "experiments" / "chip_smoke"
 
-#: decode tokens that must be the full-sequence argmax at their position,
-#: up to the reference's own bf16 noise
+#: share of float32 decode tokens that must be the full-sequence argmax
 MIN_AGREEMENT = 0.90
-#: largest noise floor, as a share of the logits' spread, at which "within
-#: the noise" still picks out a handful of the vocabulary's top tokens
-MAX_NOISE_SHARE = 0.5
+#: share of positions by which the bf16 decode tokens may match the float32
+#: argmax less often than the bf16 forward pass does: about three binomial
+#: standard deviations of a 0.3 share over 256 positions
+MAX_BF16_SHORTFALL = 0.1
 #: steps whose time includes compilation, left out of the median
 WARMUP_STEPS = 3
-#: relative error allowed between bf16 programs that differ only in layout
-BF16_RTOL = 2e-2
+#: relative error allowed between a Pallas kernel and its oracle
+KERNEL_RTOL = 2e-2
+#: relative error allowed between the sharded and the one-device train step
+#: (on four v5e chips they differed by at most 6.6e-6 in the loss and
+#: 1.3e-4 in the gradient norm)
+SHARDED_RTOL = {"loss": 1e-5, "grad_norm": 1e-3}
 
 
 class CompileClock:
@@ -84,9 +89,32 @@ def rel_err(got, want) -> float:
 # Phases
 # ---------------------------------------------------------------------------
 
+def teacher_forced_logits(model_cfg, params, prompts, out):
+    """Float32 logits of one full-sequence forward pass over the prompts and
+    all but the last generated token: ``[:, j]`` predicts ``out[:, j]``."""
+    model = build_model(model_cfg)
+    first = prompts["tokens"].shape[1] - 1
+    seq = jnp.concatenate([prompts["tokens"], jnp.asarray(out[:, :-1])], axis=1)
+    fwd = jax.jit(lambda p, t: model.forward(p, t)[0][
+        :, first:, :model_cfg.vocab_size].astype(jnp.float32))
+    return fwd(params, seq)
+
+
+def argmax_agreement(logits, tokens) -> float:
+    return float(np.mean(np.asarray(jnp.argmax(logits, -1)) == np.asarray(tokens)))
+
+
 def phase_serve(model_cfg, *, batch: int, prompt_len: int, max_new: int):
     """Greedy generation through ``Server``, checked against a full-sequence
-    forward pass over the prompt and the generated tokens."""
+    forward pass over the prompt and the generated tokens.
+
+    The witness is the same ``Server`` on the same weights with float32
+    activations and full-precision matmuls: there the decode path must pick
+    the forward pass's argmax.  bf16 rounding through a deep stack of random
+    weights moves the logits further than the gaps between the top tokens
+    (on a v5e at 40 layers the bf16 forward pass matched the float32 argmax
+    at 0.30 of positions), so in bf16 the decode path is held to matching
+    the float32 argmax about as often as the bf16 forward pass does."""
     server = build_server(model_cfg, batch=batch, prompt_len=prompt_len,
                           max_new=max_new)
     prompts = random_prompts(model_cfg, batch=batch, prompt_len=prompt_len)
@@ -97,45 +125,42 @@ def phase_serve(model_cfg, *, batch: int, prompt_len: int, max_new: int):
     out = server.generate(prompts, max_new_tokens=max_new)
     if out.shape != (batch, max_new):
         raise AssertionError(f"generated {out.shape}, want {(batch, max_new)}")
+    peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
+    logits16 = teacher_forced_logits(model_cfg, server.params, prompts, out)
 
-    # position p's logits predict token p + 1: the last generated token is
-    # predicted by the logits at the second-to-last position of the input
-    model = build_model(model_cfg)
-    forward = jax.jit(lambda p, t: model.forward(p, t)[0][..., :model_cfg.vocab_size])
-    seq = jnp.concatenate([prompts["tokens"], jnp.asarray(out[:, :-1])], axis=1)
-    logits = forward(server.params, seq)[:, prompt_len - 1:].astype(jnp.float32)
-    finite = bool(jnp.isfinite(logits).all())
-    # bf16 rounding through a deep stack of random weights moves the
-    # reference's own logits when only the sequence length changes: that
-    # move is the noise floor within which two tokens count as tied
-    short = forward(server.params, prompts["tokens"])[:, -1].astype(jnp.float32)
-    noise = float(jnp.max(jnp.abs(short - logits[:, 0])))
-    top = jnp.max(logits, axis=-1)
-    picked = jnp.take_along_axis(logits, jnp.asarray(out)[..., None], -1)[..., 0]
-    argmax_agree = float(np.mean(np.asarray(jnp.argmax(logits, -1)) == out))
-    agree = float(jnp.mean(picked >= top - noise))
-    spread = float(jnp.std(logits))
-    dev = jax.devices()[0]
-    peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
+    f32_cfg = model_cfg.replace(dtype="float32")
+    with jax.default_matmul_precision("float32"):
+        witness = Server(dataclasses.replace(server.run_cfg, model=f32_cfg),
+                         server.params)
+        out32 = witness.generate(prompts, max_new_tokens=max_new)
+        logits32 = teacher_forced_logits(f32_cfg, server.params, prompts, out32)
+        # the float32 forward pass over the bf16 run's tokens
+        ref32 = teacher_forced_logits(f32_cfg, server.params, prompts, out)
+    finite = all(bool(jnp.isfinite(x).all()) for x in (logits16, logits32, ref32))
+    res = {"prefill_s": server.stats.prefill_s,
+           "decode_tok_per_s": server.stats.decode_tok_per_s,
+           "f32_argmax_agreement": argmax_agreement(logits32, out32),
+           "bf16_argmax_agreement": argmax_agreement(logits16, out),
+           "bf16_forward_vs_f32": argmax_agreement(
+               logits16, jnp.argmax(ref32, -1)),
+           "bf16_decode_vs_f32": argmax_agreement(ref32, out),
+           "bf16_logit_error": float(jnp.max(jnp.abs(logits16 - ref32))),
+           "peak_bytes_in_use": peak}
     log("serve", model=model_cfg.name, layers=model_cfg.num_layers,
         vocab=model_cfg.vocab_size, batch=batch, prompt_len=prompt_len,
-        new_tokens=max_new, warmup_s=warmup_s, prefill_s=server.stats.prefill_s,
-        decode_tok_per_s=server.stats.decode_tok_per_s,
-        argmax_agreement=argmax_agree, logit_noise=noise,
-        agreement_within_noise=agree, logit_std=spread,
-        peak_bytes_in_use=peak)
+        new_tokens=max_new, warmup_s=warmup_s,
+        logit_std=float(jnp.std(ref32)), **res)
     if not finite:
         raise AssertionError("full-sequence logits are not all finite")
-    if not noise <= MAX_NOISE_SHARE * spread:
-        raise AssertionError(f"reference logits move by {noise} with the "
-                             f"sequence length, against a spread of {spread}")
-    if agree < MIN_AGREEMENT:
-        raise AssertionError(f"decode tokens within the reference's noise "
-                             f"{agree:.3f} < {MIN_AGREEMENT}")
-    return {"prefill_s": server.stats.prefill_s,
-            "decode_tok_per_s": server.stats.decode_tok_per_s,
-            "agreement": agree, "argmax_agreement": argmax_agree,
-            "peak_bytes_in_use": peak}
+    if res["f32_argmax_agreement"] < MIN_AGREEMENT:
+        raise AssertionError(f"float32 decode/forward argmax agreement "
+                             f"{res['f32_argmax_agreement']} < {MIN_AGREEMENT}")
+    shortfall = res["bf16_forward_vs_f32"] - res["bf16_decode_vs_f32"]
+    if shortfall > MAX_BF16_SHORTFALL:
+        raise AssertionError(f"bf16 decode matches the float32 argmax "
+                             f"{shortfall} less often than the bf16 forward "
+                             f"pass, more than {MAX_BF16_SHORTFALL}")
+    return res
 
 
 def _train(rc, ckpt_dir: Path):
@@ -215,8 +240,8 @@ def phase_kernels(cases):
         on_chip = "tpu_custom_call" in compiled.as_text()
         log("kernel", name=name, shapes=[tuple(a.shape) for a in args],
             compiled=on_chip, rel_err=err)
-        if err > BF16_RTOL:
-            raise AssertionError(f"{name}: rel err {err} > {BF16_RTOL}")
+        if err > KERNEL_RTOL:
+            raise AssertionError(f"{name}: rel err {err} > {KERNEL_RTOL}")
         results[name] = {"compiled": on_chip, "rel_err": err}
     return results
 
@@ -225,7 +250,9 @@ def phase_four_chips(model_cfg, *, batch: int, seq: int, steps: int,
                      cut_layers: int, out_dir: Path):
     """FSDP training over a (data=N, model=1) mesh of every device, then a
     ``cut_layers`` copy of the same program on that mesh and on one device
-    from the same seed, whose step-1 loss and gradient norm must agree."""
+    from the same seed, in bf16 and in float32.  The step-1 loss and
+    gradient norm must agree in both, and the step-2 loss, after one
+    update, in float32."""
     devices = jax.devices()
     mesh_cfg = make_mesh_config(len(devices))
     shape = C.ShapeConfig("train_smoke", seq, batch, "train")
@@ -244,22 +271,35 @@ def phase_four_chips(model_cfg, *, batch: int, seq: int, steps: int,
     if not np.all(np.isfinite(full.losses)):
         raise AssertionError(f"non-finite loss: {full.losses}")
 
-    cut = model_cfg.replace(num_layers=cut_layers)
-    one_step = C.TrainConfig(total_steps=1, checkpoint_every=0,
-                             checkpoint_dir=str(out_dir / "ckpt"))
-    sharded = _train(C.RunConfig(model=cut, shape=shape, mesh=mesh_cfg,
-                                 train=one_step), out_dir / "ckpt")
-    single = _train(C.RunConfig(model=cut, shape=shape,
-                                mesh=make_mesh_config(1), train=one_step),
-                    out_dir / "ckpt")
-    pairs = {"loss": (sharded.losses[0], single.losses[0]),
-             "grad_norm": (sharded.grad_norms[0], single.grad_norms[0])}
-    log("four_chips_vs_one", layers=cut_layers,
-        **{f"{k}_{n}": v for k, (a, b) in pairs.items()
-           for n, v in (("sharded", a), ("one_device", b))})
-    for name, (a, b) in pairs.items():
-        if not abs(a - b) <= BF16_RTOL * abs(b):
-            raise AssertionError(f"step-1 {name}: sharded {a} vs one device {b}")
+    # one warm-up step: the first update already takes the peak learning
+    # rate, so an update that goes wrong moves the step-2 loss
+    two_steps = C.TrainConfig(total_steps=2, warmup_steps=1, checkpoint_every=0,
+                              checkpoint_dir=str(out_dir / "ckpt"))
+    for dtype in ("bfloat16", "float32"):
+        cut = model_cfg.replace(num_layers=cut_layers, dtype=dtype)
+        sharded = _train(C.RunConfig(model=cut, shape=shape, mesh=mesh_cfg,
+                                     train=two_steps), out_dir / "ckpt")
+        single = _train(C.RunConfig(model=cut, shape=shape,
+                                    mesh=make_mesh_config(1), train=two_steps),
+                        out_dir / "ckpt")
+        pairs = {"step1_loss": (sharded.losses[0], single.losses[0]),
+                 "step1_grad_norm": (sharded.grad_norms[0], single.grad_norms[0]),
+                 "step2_loss": (sharded.losses[1], single.losses[1])}
+        log("four_chips_vs_one", layers=cut_layers, dtype=dtype,
+            **{f"{k}_{n}": v for k, (a, b) in pairs.items()
+               for n, v in (("sharded", a), ("one_device", b))})
+        for name, (a, b) in pairs.items():
+            if dtype == "bfloat16" and name == "step2_loss":
+                # Adam's first update is +-lr wherever a gradient is not
+                # zero, so bf16 rounding that flips a near-zero gradient
+                # moves that weight by 2 lr: 1.1e-4 between the bf16 step-2
+                # losses on four CPU devices at smoke widths, against 3e-7
+                # in float32 and 1.1e-3 moved by the update itself
+                continue
+            rtol = SHARDED_RTOL[name.split("_", 1)[1]]
+            if not abs(a - b) <= rtol * abs(b):
+                raise AssertionError(f"{dtype} {name}: sharded {a} vs one "
+                                     f"device {b}, rtol {rtol}")
     return peaks, state_bytes
 
 

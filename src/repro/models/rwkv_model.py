@@ -17,6 +17,13 @@ from repro.models.transformer import _remat
 
 
 class RWKVLM:
+    #: XLA options for a train step sharded over several TPU devices.  The
+    #: TPU compiler (libtpu 0.0.34) fails a scheduling RET_CHECK ("not
+    #: scheduled after its control predecessor") on the sharded step unless
+    #: async collective fusion is off, which may expose its collectives.
+    sharded_tpu_compiler_options = {
+        "xla_tpu_enable_async_collective_fusion": "false"}
+
     def __init__(self, cfg: ModelConfig, sharding: ShardingConfig = ShardingConfig()):
         self.cfg = cfg
         self.sharding = sharding

@@ -147,12 +147,9 @@ def train_bundle(run_cfg: RunConfig, mesh: Optional[Mesh] = None) -> StepBundle:
     in_sh = out_state_sh = out_sh = None
     options = None
     if mesh is not None:
-        if (run_cfg.model.family == "ssm" and mesh.size > 1
-                and mesh.devices.flat[0].platform == "tpu"):
-            # the TPU compiler (libtpu 0.0.34) fails a scheduling RET_CHECK
-            # ("not scheduled after its control predecessor") on the sharded
-            # rwkv6 train step unless async collective fusion is off
-            options = {"xla_tpu_enable_async_collective_fusion": "false"}
+        if mesh.size > 1 and mesh.devices.flat[0].platform == "tpu":
+            # a model that the TPU compiler needs steered says so itself
+            options = getattr(model, "sharded_tpu_compiler_options", None)
         state_sh = param_shardings(st_axes, rules, mesh)
         batch_sh = jax.tree.map(
             lambda a: NamedSharding(mesh, axes_to_pspec(a, rules)), batch_axes,

@@ -87,25 +87,31 @@ def test_qwen_decode_step_fits_one_chip(one_chip):
     assert 7 * 2**30 < live < V5E_HBM_BYTES
 
 
-def test_lenet_train_step_compiles(topo, monkeypatch):
-    from repro.models import build_model, lenet
-
-    # the model picks its conv lowering from the default backend, which is
-    # the CPU here: steer it to the choice it makes on the chip
-    monkeypatch.setattr(lenet.jax, "default_backend", lambda: "tpu")
+def test_lenet_train_step_compiles(topo):
     rc = C.RunConfig(model=C.get("lenet").full, shape=C.TRAIN_4K,
                      mesh=C.MeshConfig((1, 1)))
-    assert build_model(rc.model).conv_algo == "gemm"
     mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("data", "model"))
     train_bundle(rc, mesh).lower(mesh).compile()
 
 
-def test_sharded_rwkv_train_step_compiles(topo):
-    rc = C.RunConfig(model=C.get("rwkv6-1.6b").full.replace(num_layers=2),
+def _sharded_train_bundle(topo, arch):
+    """A 2-layer FSDP train step of ``arch`` over four described chips."""
+    rc = C.RunConfig(model=C.get(arch).full.replace(num_layers=2),
                      shape=C.ShapeConfig("train", 128, 8, "train"),
                      mesh=C.MeshConfig((4, 1)))
     mesh = Mesh(np.asarray(topo.devices[:4]).reshape(4, 1), ("data", "model"))
-    bundle = train_bundle(rc, mesh)
+    return train_bundle(rc, mesh), mesh
+
+
+def test_sharded_rwkv_train_step_compiles(topo):
+    bundle, mesh = _sharded_train_bundle(topo, "rwkv6-1.6b")
     assert bundle.compiler_options is not None
+    compiled = bundle.lower(mesh).compile()
+    assert "all-gather" in compiled.as_text()
+
+
+def test_sharded_dense_train_step_compiles_without_options(topo):
+    bundle, mesh = _sharded_train_bundle(topo, "qwen1.5-4b")
+    assert bundle.compiler_options is None
     compiled = bundle.lower(mesh).compile()
     assert "all-gather" in compiled.as_text()
