@@ -1,0 +1,10 @@
+"""Share of the traced decode window in which no op ran on the device."""
+KIND = "serve"
+UNIT = "%"
+
+
+def read(ctx):
+    t = ctx["trace"]
+    if not t or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
