@@ -1,0 +1,13 @@
+"""Host time inside the program's ``serve.prefill`` spans (prefill dispatch,
+cache growth and the wait for the logits), over the traced window."""
+from chipbench import program_trace
+
+KIND = "serve"
+UNIT = "%"
+
+
+def read(ctx):
+    r = program_trace.for_reduction(ctx["trace"])
+    if not r or r["window_s"] <= 0 or "serve.prefill" not in r["span_s"]:
+        return None
+    return 100.0 * r["span_s"]["serve.prefill"] / r["window_s"]

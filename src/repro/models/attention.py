@@ -4,6 +4,10 @@ The pure-jnp path here is the *reference semantics*; the Pallas flash-attention
 kernel in ``repro.kernels.flash_attention`` implements identical math with VMEM
 tiling and is swapped in through ``repro.kernels.dispatch`` when the backend
 supports it.
+
+Attention's ops run under ``jax.named_scope("attention")``, and the decode
+path's write of the new position into the cache under ``"kv_cache"``; the
+scopes sit side by side, never one inside the other.
 """
 from __future__ import annotations
 
@@ -100,6 +104,7 @@ def chunked_sdpa(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return out.swapaxes(0, 1).reshape(b, s, h, d)
 
 
+@jax.named_scope("attention")
 def attention(params: Dict, cfg: ModelConfig, x: jax.Array,
               positions: jax.Array, *, causal: bool = True,
               window: int = 0, kv_source: Optional[jax.Array] = None,
@@ -132,6 +137,7 @@ def attention(params: Dict, cfg: ModelConfig, x: jax.Array,
     return dense(out, params["wo"])
 
 
+@jax.named_scope("attention")
 def attention_prefill(params: Dict, cfg: ModelConfig, x: jax.Array,
                       positions: jax.Array, *, window: int = 0
                       ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
@@ -167,20 +173,23 @@ def attention_decode(params: Dict, cfg: ModelConfig, x: jax.Array,
     b, one, _ = x.shape
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     S = cache_k.shape[1]
-    q = dense(x, params["wq"], params.get("bq")).reshape(b, 1, h, hd)
-    k_new = dense(x, params["wk"], params.get("bk")).reshape(b, 1, kvh, hd)
-    v_new = dense(x, params["wv"], params.get("bv")).reshape(b, 1, kvh, hd)
-    posv = jnp.full((1,), pos, jnp.int32)
-    q = apply_rope(q, posv, cfg.rope_theta)
-    k_new = apply_rope(k_new, posv, cfg.rope_theta)
-    cache_k = jax.lax.dynamic_update_slice(cache_k, k_new.astype(cache_k.dtype),
-                                           (0, pos, 0, 0))
-    cache_v = jax.lax.dynamic_update_slice(cache_v, v_new.astype(cache_v.dtype),
-                                           (0, pos, 0, 0))
-    k_pos = jnp.arange(S, dtype=jnp.int32)
-    q_pos = jnp.full((1,), pos, jnp.int32)
-    bias = _mask_bias(q_pos, k_pos, causal=True, window=window,
-                      k_valid_len=pos + 1)
-    out = sdpa(q, cache_k, cache_v, bias, cfg.logit_softcap)
-    out = dense(out.reshape(b, 1, h * hd), params["wo"])
+    with jax.named_scope("attention"):
+        q = dense(x, params["wq"], params.get("bq")).reshape(b, 1, h, hd)
+        k_new = dense(x, params["wk"], params.get("bk")).reshape(b, 1, kvh, hd)
+        v_new = dense(x, params["wv"], params.get("bv")).reshape(b, 1, kvh, hd)
+        posv = jnp.full((1,), pos, jnp.int32)
+        q = apply_rope(q, posv, cfg.rope_theta)
+        k_new = apply_rope(k_new, posv, cfg.rope_theta)
+    with jax.named_scope("kv_cache"):
+        cache_k = jax.lax.dynamic_update_slice(
+            cache_k, k_new.astype(cache_k.dtype), (0, pos, 0, 0))
+        cache_v = jax.lax.dynamic_update_slice(
+            cache_v, v_new.astype(cache_v.dtype), (0, pos, 0, 0))
+    with jax.named_scope("attention"):
+        k_pos = jnp.arange(S, dtype=jnp.int32)
+        q_pos = jnp.full((1,), pos, jnp.int32)
+        bias = _mask_bias(q_pos, k_pos, causal=True, window=window,
+                          k_valid_len=pos + 1)
+        out = sdpa(q, cache_k, cache_v, bias, cfg.logit_softcap)
+        out = dense(out.reshape(b, 1, h * hd), params["wo"])
     return out, (cache_k, cache_v)

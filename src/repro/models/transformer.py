@@ -4,6 +4,11 @@ Layers are stacked on a leading axis and applied with ``lax.scan`` (+ optional
 ``jax.checkpoint``), which keeps compiled HLO size O(1) in depth — essential
 for the 512-chip dry-runs — and gives the simulator a clean while-loop trip
 count to scale per-layer cost by.
+
+Each part of a step runs under one flat ``jax.named_scope`` (``embed``,
+``norm``, ``attention`` and ``kv_cache`` from ``attention.py``, ``mlp``,
+``head``), which names its ops in the HLO metadata, so a device trace can
+charge op time to a part whatever numbers XLA gives its fusions.
 """
 from __future__ import annotations
 
@@ -88,11 +93,12 @@ class DecoderLM:
 
     # ---------------------------------------------------------------- embed
     def _embed(self, params, tokens, frontend_emb=None, seq_axis="act_seq"):
-        tbl = lc(params["embed"], (None, "embed_tbl"))
-        x = jnp.take(tbl, tokens, axis=0).astype(jnp.dtype(self.cfg.dtype))
-        if frontend_emb is not None:
-            x = jnp.concatenate([frontend_emb.astype(x.dtype), x], axis=1)
-        return lc(x, ("batch", seq_axis, "embed"))
+        with jax.named_scope("embed"):
+            tbl = lc(params["embed"], (None, "embed_tbl"))
+            x = jnp.take(tbl, tokens, axis=0).astype(jnp.dtype(self.cfg.dtype))
+            if frontend_emb is not None:
+                x = jnp.concatenate([frontend_emb.astype(x.dtype), x], axis=1)
+            return lc(x, ("batch", seq_axis, "embed"))
 
     def _window_for(self, idx):
         cfg = self.cfg
@@ -113,18 +119,21 @@ class DecoderLM:
             x, aux = carry
             p_l, idx = inp
             window = self._window_for(idx)
-            h = rms_norm(x, p_l["ln1"], cfg.norm_eps)
+            with jax.named_scope("norm"):
+                h = rms_norm(x, p_l["ln1"], cfg.norm_eps)
             h = attn.attention(p_l["attn"], cfg, h, positions, window=window)
             x = x + h
-            h = rms_norm(x, p_l["ln2"], cfg.norm_eps)
-            if cfg.family == "moe":
-                h, a = moe_mod.moe_ffn(p_l["moe"], cfg, h,
-                                       capacity_factor=self.moe_capacity,
-                                       gather_once=self.sharding.moe_gather_once)
-                aux = aux + a
-            else:
-                h = swiglu(h, p_l["ffn"]["w_gate"], p_l["ffn"]["w_up"],
-                           p_l["ffn"]["w_down"])
+            with jax.named_scope("norm"):
+                h = rms_norm(x, p_l["ln2"], cfg.norm_eps)
+            with jax.named_scope("mlp"):
+                if cfg.family == "moe":
+                    h, a = moe_mod.moe_ffn(p_l["moe"], cfg, h,
+                                           capacity_factor=self.moe_capacity,
+                                           gather_once=self.sharding.moe_gather_once)
+                    aux = aux + a
+                else:
+                    h = swiglu(h, p_l["ffn"]["w_gate"], p_l["ffn"]["w_up"],
+                               p_l["ffn"]["w_down"])
             x = lc(x + h, ("batch", "act_seq", "embed"))
             return (x, aux), None
 
@@ -132,12 +141,14 @@ class DecoderLM:
         idxs = jnp.arange(cfg.num_layers, dtype=jnp.int32)
         (x, aux), _ = jax.lax.scan(layer, (x, jnp.zeros((), jnp.float32)),
                                    (params["layers"], idxs))
-        return rms_norm(x, params["ln_f"], cfg.norm_eps), aux
+        with jax.named_scope("norm"):
+            return rms_norm(x, params["ln_f"], cfg.norm_eps), aux
 
     def forward(self, params, tokens, frontend_emb=None):
         """Full logits (test/debug convenience; training uses chunked loss)."""
         x, aux = self.hidden(params, tokens, frontend_emb)
-        logits = jnp.einsum("bsd,dv->bsv", x, params["head"])
+        with jax.named_scope("head"):
+            logits = jnp.einsum("bsd,dv->bsv", x, params["head"])
         return lc(logits, ("batch", "act_seq", "vocab")), aux
 
     def loss(self, params, batch) -> Tuple[jax.Array, Dict[str, jax.Array]]:
@@ -164,24 +175,29 @@ class DecoderLM:
         def layer(x, inp):
             p_l, idx = inp
             window = self._window_for(idx)
-            h = rms_norm(x, p_l["ln1"], cfg.norm_eps)
+            with jax.named_scope("norm"):
+                h = rms_norm(x, p_l["ln1"], cfg.norm_eps)
             h, (k, v) = attn.attention_prefill(p_l["attn"], cfg, h, positions,
                                                window=window)
             x = x + h
-            h = rms_norm(x, p_l["ln2"], cfg.norm_eps)
-            if cfg.family == "moe":
-                h, _ = moe_mod.moe_ffn(p_l["moe"], cfg, h,
-                                       capacity_factor=self.moe_capacity,
-                                       gather_once=self.sharding.moe_gather_once)
-            else:
-                h = swiglu(h, p_l["ffn"]["w_gate"], p_l["ffn"]["w_up"],
-                           p_l["ffn"]["w_down"])
+            with jax.named_scope("norm"):
+                h = rms_norm(x, p_l["ln2"], cfg.norm_eps)
+            with jax.named_scope("mlp"):
+                if cfg.family == "moe":
+                    h, _ = moe_mod.moe_ffn(p_l["moe"], cfg, h,
+                                           capacity_factor=self.moe_capacity,
+                                           gather_once=self.sharding.moe_gather_once)
+                else:
+                    h = swiglu(h, p_l["ffn"]["w_gate"], p_l["ffn"]["w_up"],
+                               p_l["ffn"]["w_down"])
             return lc(x + h, ("batch", "act_seq", "embed")), (k, v)
 
         idxs = jnp.arange(cfg.num_layers, dtype=jnp.int32)
         x, (ks, vs) = jax.lax.scan(layer, x, (params["layers"], idxs))
-        x = rms_norm(x[:, -1:], params["ln_f"], cfg.norm_eps)
-        logits = jnp.einsum("bsd,dv->bsv", x, params["head"])
+        with jax.named_scope("norm"):
+            x = rms_norm(x[:, -1:], params["ln_f"], cfg.norm_eps)
+        with jax.named_scope("head"):
+            logits = jnp.einsum("bsd,dv->bsv", x, params["head"])
         cache = {"k": lc(ks, ("layers", "batch", "kv_seq", "kv_heads", "head_dim")),
                  "v": lc(vs, ("layers", "batch", "kv_seq", "kv_heads", "head_dim")),
                  "pos": jnp.asarray(s_total, jnp.int32)}
@@ -192,9 +208,10 @@ class DecoderLM:
         """batch: {"token": (b, 1) int32}. Returns (logits, new cache)."""
         cfg = self.cfg
         pos = cache["pos"]
-        x = jnp.take(params["embed"], batch["token"], axis=0).astype(
-            jnp.dtype(self.cfg.dtype))
-        x = lc(x, ("batch", "seq", "embed"))   # decode: seq dim is 1, unsharded
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], batch["token"], axis=0).astype(
+                jnp.dtype(self.cfg.dtype))
+            x = lc(x, ("batch", "seq", "embed"))   # decode: seq dim is 1, unsharded
 
         def layer(carry, inp):
             # cache as CARRY with in-place per-layer slice updates: the while
@@ -203,27 +220,34 @@ class DecoderLM:
             x, ck_all, cv_all = carry
             p_l, idx = inp
             window = self._window_for(idx)
-            ck = jax.lax.dynamic_index_in_dim(ck_all, idx, 0, keepdims=False)
-            cv = jax.lax.dynamic_index_in_dim(cv_all, idx, 0, keepdims=False)
-            h = rms_norm(x, p_l["ln1"], cfg.norm_eps)
+            with jax.named_scope("kv_cache"):
+                ck = jax.lax.dynamic_index_in_dim(ck_all, idx, 0, keepdims=False)
+                cv = jax.lax.dynamic_index_in_dim(cv_all, idx, 0, keepdims=False)
+            with jax.named_scope("norm"):
+                h = rms_norm(x, p_l["ln1"], cfg.norm_eps)
             h, (ck, cv) = attn.attention_decode(p_l["attn"], cfg, h, ck, cv, pos,
                                                 window=window)
-            ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, idx, 0)
-            cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, idx, 0)
+            with jax.named_scope("kv_cache"):
+                ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, idx, 0)
+                cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, idx, 0)
             x = x + h
-            h = rms_norm(x, p_l["ln2"], cfg.norm_eps)
-            if cfg.family == "moe":
-                h, _ = moe_mod.moe_ffn(p_l["moe"], cfg, h, capacity_factor=0.0)
-            else:
-                h = swiglu(h, p_l["ffn"]["w_gate"], p_l["ffn"]["w_up"],
-                           p_l["ffn"]["w_down"])
+            with jax.named_scope("norm"):
+                h = rms_norm(x, p_l["ln2"], cfg.norm_eps)
+            with jax.named_scope("mlp"):
+                if cfg.family == "moe":
+                    h, _ = moe_mod.moe_ffn(p_l["moe"], cfg, h, capacity_factor=0.0)
+                else:
+                    h = swiglu(h, p_l["ffn"]["w_gate"], p_l["ffn"]["w_up"],
+                               p_l["ffn"]["w_down"])
             return (x + h, ck_all, cv_all), None
 
         idxs = jnp.arange(cfg.num_layers, dtype=jnp.int32)
         (x, ks, vs), _ = jax.lax.scan(layer, (x, cache["k"], cache["v"]),
                                       (params["layers"], idxs))
-        x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-        logits = jnp.einsum("bsd,dv->bsv", x, params["head"])
+        with jax.named_scope("norm"):
+            x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+        with jax.named_scope("head"):
+            logits = jnp.einsum("bsd,dv->bsv", x, params["head"])
         new_cache = {"k": ks, "v": vs, "pos": pos + 1}
         return logits, new_cache
 

@@ -33,8 +33,16 @@ Usage::
     events = TRACER.to_chrome_events()      # compose into any trace file
 
 The module-level :data:`TRACER` is the instance every instrumented layer
-(engine, fastsched, cluster events, topology lowering, faults) uses; tests
-may build private :class:`SpanTracer` instances.
+(engine, fastsched, cluster events, topology lowering, faults, the
+serving loop) uses; tests may build private :class:`SpanTracer` instances.
+
+Code that runs on an accelerator opens its spans with
+:meth:`SpanTracer.annotated` instead: each such span is also a
+``jax.profiler.TraceAnnotation``, so it lands on the host plane of any
+running ``jax.profiler`` trace, on the same clock as the device's ops, and
+a trace reduction can say what the host was doing while the device sat
+idle.  ``jax`` is imported only when such a span opens, so the engine and
+cluster layers still load without it.
 """
 from __future__ import annotations
 
@@ -118,6 +126,25 @@ class _Span:
         return False
 
 
+class _AnnotatedSpan(_Span):
+    """Live span that also holds a profiler ``TraceAnnotation`` open."""
+
+    __slots__ = ("_annotation",)
+
+    def __init__(self, tracer: "SpanTracer", name: str,
+                 attrs: Optional[Dict[str, Any]], annotation):
+        super().__init__(tracer, name, attrs)
+        self._annotation = annotation
+
+    def __enter__(self) -> "_AnnotatedSpan":
+        self._annotation.__enter__()
+        return super().__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        super().__exit__(*exc)
+        return self._annotation.__exit__(*exc)
+
+
 class SpanTracer:
     """Ring-buffered hierarchical span recorder (see module docstring)."""
 
@@ -141,6 +168,19 @@ class SpanTracer:
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, attrs or None)
+
+    def annotated(self, name: str, **attrs: Any):
+        """Context manager for a span that a device profile must see.
+
+        It always enters a ``jax.profiler.TraceAnnotation(name)`` (about a
+        microsecond when no profiler session runs), and records into the
+        ring like :meth:`span` only while the tracer is enabled.  ``attrs``
+        go to the ring record alone: the annotation carries the bare name,
+        which is what a trace reduction matches."""
+        from jax.profiler import TraceAnnotation
+        if not self.enabled:
+            return TraceAnnotation(name)
+        return _AnnotatedSpan(self, name, attrs or None, TraceAnnotation(name))
 
     def instant(self, name: str, **attrs: Any) -> None:
         """Record a zero-duration marker (FAIL/REPAIR events, gang kills)."""

@@ -4,6 +4,13 @@
 exposes ``generate``: prefill a batch of prompts, then greedy/temperature
 decode for N tokens.  Slot-based batching (a finished sequence's slot can be
 refilled) is modeled by the per-slot ``done`` mask.
+
+``generate`` opens its spans with ``TRACER.annotated``, so a
+``jax.profiler`` trace shows them on the device's clock: ``serve.generate``
+around the call, ``serve.prefill`` around the prefill and cache growth up
+to the logits being ready, and for every token ``serve.token`` holding
+``serve.step`` (the decode dispatch), ``serve.sample`` and ``serve.fetch``
+(the wait for the token on the host).
 """
 from __future__ import annotations
 
@@ -17,6 +24,7 @@ import numpy as np
 
 from repro.config import RunConfig
 from repro.models import build_model
+from repro.obs.trace import TRACER
 from repro.runtime.steps import decode_bundle, prefill_bundle
 
 
@@ -25,6 +33,9 @@ class ServeStats:
     prefill_s: float = 0.0
     decode_s: float = 0.0
     tokens_out: int = 0
+    #: ``time.perf_counter()`` at which each token of the last ``generate``
+    #: call reached the host, the first one after prefill included
+    token_times: List[float] = field(default_factory=list)
 
     @property
     def decode_tok_per_s(self) -> float:
@@ -68,29 +79,42 @@ class Server:
     def generate(self, batch: Dict[str, Any], max_new_tokens: int = 16,
                  seed: int = 0) -> np.ndarray:
         """Prefill the prompt batch, then decode up to max_new_tokens."""
-        t0 = time.time()
-        logits, cache = self._prefill(self.params, batch)
-        cache = self._grow_cache(cache, max_new_tokens)
-        jax.block_until_ready(logits)
-        self.stats.prefill_s += time.time() - t0
+        with TRACER.annotated("serve.generate"):
+            return self._generate(batch, max_new_tokens, seed)
+
+    def _generate(self, batch, max_new_tokens: int, seed: int) -> np.ndarray:
+        times = self.stats.token_times = []
+        t0 = time.perf_counter()
+        with TRACER.annotated("serve.prefill"):
+            logits, cache = self._prefill(self.params, batch)
+            cache = self._grow_cache(cache, max_new_tokens)
+            jax.block_until_ready(logits)
+        self.stats.prefill_s += time.perf_counter() - t0
 
         key = jax.random.key(seed)
-        tok = self._sample(logits, key)
-        b = tok.shape[0]
-        out = [np.asarray(tok)]
-        done = np.zeros(b, bool)
-        t0 = time.time()
+        with TRACER.annotated("serve.sample"):
+            tok = self._sample(logits, key)
+        with TRACER.annotated("serve.fetch"):
+            out = [np.asarray(tok)]
+        times.append(time.perf_counter())
+        done = np.zeros(tok.shape[0], bool)
+        t0 = time.perf_counter()
         for i in range(max_new_tokens - 1):
-            key, sub = jax.random.split(key)
-            logits, cache = self._decode(self.params, cache,
-                                         {"token": tok[:, None]})
-            tok = self._sample(logits, sub)
-            arr = np.asarray(tok)
+            with TRACER.annotated("serve.token"):
+                with TRACER.annotated("serve.step"):
+                    logits, cache = self._decode(self.params, cache,
+                                                 {"token": tok[:, None]})
+                with TRACER.annotated("serve.sample"):
+                    key, sub = jax.random.split(key)
+                    tok = self._sample(logits, sub)
+                with TRACER.annotated("serve.fetch"):
+                    arr = np.asarray(tok)
+                times.append(time.perf_counter())
             done |= arr == self.eos
             out.append(arr)
             self.stats.tokens_out += int((~done).sum())
             if done.all():
                 break
         jax.block_until_ready(tok)
-        self.stats.decode_s += time.time() - t0
+        self.stats.decode_s += time.perf_counter() - t0
         return np.stack(out, axis=1)

@@ -46,6 +46,11 @@ class StepBundle:
         return jitted.lower(*self.abstract_inputs)
 
     def jit(self):
+        # The persistent compilation cache leaves metadata out of its key by
+        # default: a hit could then return an executable built from other
+        # source, whose ops carry that source's named scopes, and a device
+        # trace would charge their time to stale names.
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
         kw = {}
         if self.in_shardings is not None:
             kw["in_shardings"] = self.in_shardings
