@@ -162,6 +162,35 @@ def attention_prefill(params: Dict, cfg: ModelConfig, x: jax.Array,
     return out, (k, v)
 
 
+def _decode_qkv(params: Dict, cfg: ModelConfig, x: jax.Array, pos: jax.Array):
+    """The new token's roped q and its k/v, each (b, 1, heads, hd)."""
+    b = x.shape[0]
+    h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
+    with jax.named_scope("attention"):
+        q = dense(x, params["wq"], params.get("bq")).reshape(b, 1, h, hd)
+        k_new = dense(x, params["wk"], params.get("bk")).reshape(b, 1, kvh, hd)
+        v_new = dense(x, params["wv"], params.get("bv")).reshape(b, 1, kvh, hd)
+        posv = jnp.full((1,), pos, jnp.int32)
+        q = apply_rope(q, posv, cfg.rope_theta)
+        k_new = apply_rope(k_new, posv, cfg.rope_theta)
+    return q, k_new, v_new
+
+
+def _decode_attend(params: Dict, cfg: ModelConfig, q: jax.Array,
+                   cache_k: jax.Array, cache_v: jax.Array, pos: jax.Array,
+                   window) -> jax.Array:
+    """The new token's attention over positions ``<= pos`` of one layer's
+    (b, S, kv, hd) cache, projected out."""
+    b, _, h, hd = q.shape
+    with jax.named_scope("attention"):
+        k_pos = jnp.arange(cache_k.shape[1], dtype=jnp.int32)
+        q_pos = jnp.full((1,), pos, jnp.int32)
+        bias = _mask_bias(q_pos, k_pos, causal=True, window=window,
+                          k_valid_len=pos + 1)
+        out = sdpa(q, cache_k, cache_v, bias, cfg.logit_softcap)
+        return dense(out.reshape(b, 1, h * hd), params["wo"])
+
+
 def attention_decode(params: Dict, cfg: ModelConfig, x: jax.Array,
                      cache_k: jax.Array, cache_v: jax.Array, pos: jax.Array,
                      *, window: int = 0
@@ -170,26 +199,33 @@ def attention_decode(params: Dict, cfg: ModelConfig, x: jax.Array,
 
     ``pos`` is the scalar index of the new token (same for the whole batch).
     """
-    b, one, _ = x.shape
-    h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
-    S = cache_k.shape[1]
-    with jax.named_scope("attention"):
-        q = dense(x, params["wq"], params.get("bq")).reshape(b, 1, h, hd)
-        k_new = dense(x, params["wk"], params.get("bk")).reshape(b, 1, kvh, hd)
-        v_new = dense(x, params["wv"], params.get("bv")).reshape(b, 1, kvh, hd)
-        posv = jnp.full((1,), pos, jnp.int32)
-        q = apply_rope(q, posv, cfg.rope_theta)
-        k_new = apply_rope(k_new, posv, cfg.rope_theta)
+    q, k_new, v_new = _decode_qkv(params, cfg, x, pos)
     with jax.named_scope("kv_cache"):
         cache_k = jax.lax.dynamic_update_slice(
             cache_k, k_new.astype(cache_k.dtype), (0, pos, 0, 0))
         cache_v = jax.lax.dynamic_update_slice(
             cache_v, v_new.astype(cache_v.dtype), (0, pos, 0, 0))
+    out = _decode_attend(params, cfg, q, cache_k, cache_v, pos, window)
+    return out, (cache_k, cache_v)
+
+
+def attention_decode_stacked(params: Dict, cfg: ModelConfig, x: jax.Array,
+                             cache_k: jax.Array, cache_v: jax.Array,
+                             idx: jax.Array, pos: jax.Array, *, window: int = 0
+                             ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
+    """One-token decode of layer ``idx`` against the stacked (L, b, S, kv, hd)
+    cache, in place: the new position is written into the stack and the
+    layer's K/V are read where they lie, never copied out and back.
+    Returns the updated stacked cache."""
+    q, k_new, v_new = _decode_qkv(params, cfg, x, pos)
+    with jax.named_scope("kv_cache"):
+        at = (idx, 0, pos, 0, 0)
+        cache_k = jax.lax.dynamic_update_slice(
+            cache_k, k_new[None].astype(cache_k.dtype), at)
+        cache_v = jax.lax.dynamic_update_slice(
+            cache_v, v_new[None].astype(cache_v.dtype), at)
     with jax.named_scope("attention"):
-        k_pos = jnp.arange(S, dtype=jnp.int32)
-        q_pos = jnp.full((1,), pos, jnp.int32)
-        bias = _mask_bias(q_pos, k_pos, causal=True, window=window,
-                          k_valid_len=pos + 1)
-        out = sdpa(q, cache_k, cache_v, bias, cfg.logit_softcap)
-        out = dense(out.reshape(b, 1, h * hd), params["wo"])
+        ck = jax.lax.dynamic_index_in_dim(cache_k, idx, 0, keepdims=False)
+        cv = jax.lax.dynamic_index_in_dim(cache_v, idx, 0, keepdims=False)
+    out = _decode_attend(params, cfg, q, ck, cv, pos, window)
     return out, (cache_k, cache_v)

@@ -214,22 +214,17 @@ class DecoderLM:
             x = lc(x, ("batch", "seq", "embed"))   # decode: seq dim is 1, unsharded
 
         def layer(carry, inp):
-            # cache as CARRY with in-place per-layer slice updates: the while
-            # loop aliases carries, so the KV cache exists ONCE in HBM
-            # (cache-as-xs/ys held 2x live copies -> OOM on 32k decode cells)
+            # cache as CARRY, written one position per layer in place and read
+            # where it lies: the while loop aliases carries, so the KV cache
+            # exists ONCE in HBM (cache-as-xs/ys held 2x live copies -> OOM on
+            # 32k decode cells), and no layer copies its slice out and back
             x, ck_all, cv_all = carry
             p_l, idx = inp
             window = self._window_for(idx)
-            with jax.named_scope("kv_cache"):
-                ck = jax.lax.dynamic_index_in_dim(ck_all, idx, 0, keepdims=False)
-                cv = jax.lax.dynamic_index_in_dim(cv_all, idx, 0, keepdims=False)
             with jax.named_scope("norm"):
                 h = rms_norm(x, p_l["ln1"], cfg.norm_eps)
-            h, (ck, cv) = attn.attention_decode(p_l["attn"], cfg, h, ck, cv, pos,
-                                                window=window)
-            with jax.named_scope("kv_cache"):
-                ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, idx, 0)
-                cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, idx, 0)
+            h, (ck_all, cv_all) = attn.attention_decode_stacked(
+                p_l["attn"], cfg, h, ck_all, cv_all, idx, pos, window=window)
             x = x + h
             with jax.named_scope("norm"):
                 h = rms_norm(x, p_l["ln2"], cfg.norm_eps)

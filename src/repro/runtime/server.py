@@ -3,7 +3,9 @@
 ``Server`` owns jitted prefill/decode step functions for one RunConfig and
 exposes ``generate``: prefill a batch of prompts, then greedy/temperature
 decode for N tokens.  Slot-based batching (a finished sequence's slot can be
-refilled) is modeled by the per-slot ``done`` mask.
+refilled) is modeled by the per-slot ``done`` mask.  The decode step takes
+the KV cache in the layout its compiled program chose, and ``generate``
+grows the prefill cache into that layout once, before the first step.
 
 ``generate`` opens its spans with ``TRACER.annotated``, so a
 ``jax.profiler`` trace shows them on the device's clock: ``serve.generate``
@@ -14,6 +16,7 @@ to the logits being ready, and for every token ``serve.token`` holding
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
@@ -25,7 +28,7 @@ import numpy as np
 from repro.config import RunConfig
 from repro.models import build_model
 from repro.obs.trace import TRACER
-from repro.runtime.steps import decode_bundle, prefill_bundle
+from repro.runtime.steps import decode_bundle, is_kv_leaf, prefill_bundle
 
 
 @dataclass
@@ -42,6 +45,63 @@ class ServeStats:
         return self.tokens_out / self.decode_s if self.decode_s else 0.0
 
 
+@contextlib.contextmanager
+def _uncached_compiles():
+    """Compile without JAX's persistent compilation cache.  An executable
+    read back from that cache (jax 0.9.0 on a TPU v5e) labels each output
+    in a layout of the compiler's choosing with the default layout instead;
+    the step that reads that output then refuses it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    enabled = jax.config.values["jax_enable_compilation_cache"]
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        compilation_cache.reset_cache()
+
+
+def _abstract(tree):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=getattr(x, "sharding", None)),
+        tree)
+
+
+class CompiledPerShape:
+    """A jitted step whose arguments take the formats its compiled program
+    chose (``Layout.AUTO``): compiled once per argument shapes, outside the
+    persistent cache, and called as compiled, so an argument in another
+    layout is refused, not copied."""
+
+    def __init__(self, jitted):
+        self._jitted = jitted
+        self._compiled: Dict[Any, Any] = {}
+
+    def compiled(self, *args):
+        """The executable for these arguments' shapes (arrays or
+        ``ShapeDtypeStruct``s), compiled on first use."""
+        key = tuple((x.shape, x.dtype) for x in jax.tree.leaves(args))
+        exe = self._compiled.get(key)
+        if exe is None:
+            with _uncached_compiles():
+                exe = self._jitted.lower(*_abstract(args)).compile()
+            self._compiled[key] = exe
+        return exe
+
+    def __call__(self, *args):
+        return self.compiled(*args)(*args)
+
+
+def _pad_positions(x: jax.Array, extra: int) -> jax.Array:
+    """A KV cache leaf (L, b, S, kv, hd) with ``extra`` more positions, grown
+    a layer at a time: a change of layout on the way then needs one
+    layer's room, not the whole leaf's."""
+    return jax.lax.map(
+        lambda xl: jnp.pad(xl, [(0, 0), (0, extra), (0, 0), (0, 0)]), x)
+
+
 class Server:
     def __init__(self, run_cfg: RunConfig, params: Any, mesh=None,
                  eos_token: int = 0, temperature: float = 0.0):
@@ -51,7 +111,11 @@ class Server:
         self.eos = eos_token
         self.temperature = temperature
         self._prefill = prefill_bundle(run_cfg, mesh).jit()
-        self._decode = decode_bundle(run_cfg, mesh).jit()
+        # the growth asks ``_decode_step`` for the formats; ``_decode`` is
+        # what the token loop calls, which a caller may wrap
+        self._decode = self._decode_step = CompiledPerShape(
+            decode_bundle(run_cfg, mesh).jit())
+        self._pads: Dict[Any, Any] = {}
         self.stats = ServeStats()
 
     def _sample(self, logits: jax.Array, key) -> jax.Array:
@@ -60,21 +124,41 @@ class Server:
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return jax.random.categorical(key, logits / self.temperature).astype(jnp.int32)
 
-    @staticmethod
-    def _grow_cache(cache, extra: int):
-        """Pad KV caches (rank-5 leaves named k/v) so decode has capacity for
-        ``extra`` new positions; O(1) recurrent states need no growth."""
-        if isinstance(cache, dict):
-            out = {}
-            for key, v in cache.items():
-                if key in ("k", "v") and hasattr(v, "ndim") and v.ndim == 5:
-                    pad = [(0, 0)] * 5
-                    pad[2] = (0, extra)
-                    out[key] = jnp.pad(v, pad)
-                else:
-                    out[key] = Server._grow_cache(v, extra)
+    def _grow_cache(self, cache, extra: int, batch: int):
+        """Grow the prefill cache's K/V leaves by ``extra`` positions, each
+        straight into the format the decode step takes it in: the cache's
+        one relayout, after which every step takes the previous one's cache
+        as it is.  Each prefill leaf is freed once grown, so that the last
+        leaf grows beside the rest of the grown cache and nothing more."""
+        grown = jax.tree_util.tree_map_with_path(
+            lambda path, x: jax.eval_shape(lambda y: _pad_positions(y, extra), x)
+            if is_kv_leaf(path, x) else x, cache)
+        tok = jax.ShapeDtypeStruct((batch, 1), jnp.int32)
+        exe = self._decode_step.compiled(self.params, grown, {"token": tok})
+        (_, formats, _), _ = exe.input_formats
+        if exe.output_formats[1] != formats:
+            raise RuntimeError(
+                f"the decode step takes its cache in {formats} but returns it "
+                f"in {exe.output_formats[1]}: each step would relayout it")
+
+        def grow(path, leaf, fmt):
+            if not is_kv_leaf(path, leaf):
+                return leaf
+            key = (leaf.shape, leaf.dtype, extra, fmt)
+            pad = self._pads.get(key)
+            if pad is None:
+                with _uncached_compiles():
+                    pad = jax.jit(_pad_positions, static_argnums=1,
+                                  out_shardings=fmt).lower(leaf, extra).compile()
+                self._pads[key] = pad
+            # not donated, as XLA cannot reuse a buffer smaller than the
+            # output: freed by hand once the pad is done, before the next
+            # leaf's output is allocated
+            out = jax.block_until_ready(pad(leaf))
+            leaf.delete()
             return out
-        return cache
+
+        return jax.tree_util.tree_map_with_path(grow, cache, formats)
 
     def generate(self, batch: Dict[str, Any], max_new_tokens: int = 16,
                  seed: int = 0) -> np.ndarray:
@@ -87,7 +171,7 @@ class Server:
         t0 = time.perf_counter()
         with TRACER.annotated("serve.prefill"):
             logits, cache = self._prefill(self.params, batch)
-            cache = self._grow_cache(cache, max_new_tokens)
+            cache = self._grow_cache(cache, max_new_tokens, logits.shape[0])
             jax.block_until_ready(logits)
         self.stats.prefill_s += time.perf_counter() - t0
 

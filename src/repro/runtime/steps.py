@@ -15,6 +15,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Format, Layout
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.config import RunConfig
@@ -33,7 +34,7 @@ class StepBundle:
     """Everything needed to lower/compile/run one step function."""
     fn: Callable
     abstract_inputs: Tuple[Any, ...]          # pytrees of ShapeDtypeStruct
-    in_shardings: Optional[Tuple[Any, ...]]   # NamedShardings (None w/o mesh)
+    in_shardings: Optional[Tuple[Any, ...]]   # NamedShardings or Formats (None: unset)
     out_shardings: Optional[Any]
     donate_argnums: Tuple[int, ...] = ()
     compiler_options: Optional[Dict[str, str]] = None
@@ -219,8 +220,22 @@ def prefill_bundle(run_cfg: RunConfig, mesh: Optional[Mesh] = None) -> StepBundl
     return StepBundle(fn, (params_sds, batch_sds), in_sh, out_sh)
 
 
+def is_kv_leaf(path, leaf) -> bool:
+    """A rank-5 KV cache leaf, keyed ``k`` or ``v`` at any depth."""
+    key = getattr(path[-1], "key", None) if path else None
+    return key in ("k", "v") and getattr(leaf, "ndim", 0) == 5
+
+
 def decode_bundle(run_cfg: RunConfig, mesh: Optional[Mesh] = None) -> StepBundle:
-    """One-token serve_step against a full-length cache (decode_* shapes)."""
+    """One-token serve_step against a full-length cache (decode_* shapes).
+
+    The cache's K/V leaves take the layout the compiler chooses for the
+    decode loop (``Layout.AUTO``) on input and output, so that a step's
+    cache feeds the next step as it is, with no relayout between steps.  A
+    concrete array can only be passed to the step once it is compiled:
+    ``lower(...).compile()``, then ``Compiled.input_formats`` says the
+    layout to put the cache in (``Server`` does this once per shape).
+    """
     model = build_model(run_cfg.model, run_cfg.sharding)
     rules = _rules(run_cfg, model)
 
@@ -230,8 +245,11 @@ def decode_bundle(run_cfg: RunConfig, mesh: Optional[Mesh] = None) -> StepBundle
     fn = _ambient(decode_step, rules, mesh, run_cfg.sharding)
     params_sds = model.abstract()
     cache_sds, cache_axes, tok_sds, tok_axes = model.decode_state_specs(run_cfg.shape)
-    in_sh = out_sh = None
-    if mesh is not None:
+    params_sh = cache_sh = tok_sh = logits_sh = None
+    if mesh is None:
+        # leaves left None follow their argument: the single device's sharding
+        cache_sh = jax.tree.map(lambda _: None, cache_sds)
+    else:
         params_sh = param_shardings(model.axes(), rules, mesh)
         cache_sh = jax.tree.map(
             lambda a: NamedSharding(mesh, axes_to_pspec(a, rules)), cache_axes,
@@ -239,10 +257,12 @@ def decode_bundle(run_cfg: RunConfig, mesh: Optional[Mesh] = None) -> StepBundle
         tok_sh = jax.tree.map(
             lambda a: NamedSharding(mesh, axes_to_pspec(a, rules)), tok_axes,
             is_leaf=lambda x: isinstance(x, tuple))
-        in_sh = (params_sh, cache_sh, tok_sh)
         logits_sh = NamedSharding(mesh, axes_to_pspec(("batch", None, "vocab"), rules))
-        out_sh = (logits_sh, cache_sh)
-    return StepBundle(fn, (params_sds, cache_sds, tok_sds), in_sh, out_sh,
+    cache_fmt = jax.tree_util.tree_map_with_path(
+        lambda path, sds, sh: Format(Layout.AUTO, sh) if is_kv_leaf(path, sds) else sh,
+        cache_sds, cache_sh, is_leaf=lambda x: x is None)
+    return StepBundle(fn, (params_sds, cache_sds, tok_sds),
+                      (params_sh, cache_fmt, tok_sh), (logits_sh, cache_fmt),
                       donate_argnums=(1,))
 
 

@@ -112,6 +112,48 @@ def test_moe_nodrop_consistency():
                                rtol=2e-2, atol=1e-3)
 
 
+@pytest.mark.parametrize("arch,window", [
+    ("qwen1.5-4b", None),
+    ("gemma3-12b", 4),           # local layers' window shorter than the run
+    ("qwen3-moe-30b-a3b", None),
+])
+def test_server_generate_across_cache_growth_matches_forward(arch, window,
+                                                             monkeypatch):
+    """``Server.generate`` prefills 8 tokens, grows the cache into the
+    decode step's compiled formats and decodes 8 more: every greedy token
+    is the full-sequence forward's argmax at its position.  Routing drops
+    no token, so that prefill and forward route alike."""
+    from repro.models import moe
+    from repro.runtime.server import Server
+    from repro.runtime.steps import init_params
+
+    monkeypatch.setattr(moe, "_capacity", lambda tokens, cfg, factor: tokens)
+    cfg = C.get(arch).smoke
+    if window is not None:
+        cfg = cfg.replace(window_size=window)
+    rc = C.RunConfig(model=cfg, shape=C.ShapeConfig("serve", 16, 2, "prefill"),
+                     mesh=C.SMOKE_MESH)
+    params = init_params(rc, jax.random.key(0))
+    server = Server(rc, params, eos_token=-1)
+    prompts = jax.random.randint(jax.random.key(1), (2, 8), 0, cfg.vocab_size)
+    out = server.generate({"tokens": prompts}, max_new_tokens=8)
+
+    seq = jnp.concatenate([prompts, jnp.asarray(out)], axis=1)
+    full, _ = build_model(cfg).forward(params, seq[:, :-1])
+    want = np.asarray(jnp.argmax(full[:, 7:, :cfg.vocab_size], axis=-1))
+    np.testing.assert_array_equal(out, want)
+
+    # a step's cache comes out in the formats the step takes it in
+    _, cache = server._prefill(params, {"tokens": prompts})
+    cache = server._grow_cache(cache, 8, 2)
+    tok = {"token": prompts[:, :1]}
+    (_, formats, _), _ = server._decode.compiled(params, cache, tok).input_formats
+    grown = {leaf: cache[leaf].format for leaf in ("k", "v")}
+    _, cache = server._decode(params, cache, tok)      # donates the grown cache
+    for leaf in ("k", "v"):
+        assert grown[leaf] == cache[leaf].format == formats[leaf]
+
+
 def test_gemma_window_pattern():
     """gemma3 smoke: global layers attend beyond the window, local don't."""
     cfg = C.get("gemma3-12b").smoke  # window 16, global every 2

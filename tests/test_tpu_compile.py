@@ -8,6 +8,7 @@ topology is described inside a fixture, so a worker that is not given this
 file never loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,7 @@ import pytest
 from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro import config as C
+from repro.core.hlo_ir import parse_hlo_module
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
 from repro.kernels.tiled_matmul.kernel import tiled_matmul
 from repro.kernels.winograd.kernel import winograd_tiles
@@ -85,6 +87,65 @@ def test_qwen_decode_step_fits_one_chip(one_chip):
     live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 7 * 2**30 < live < V5E_HBM_BYTES
+
+
+def _parse_tpu_hlo(text):
+    """The module, with the TPU's tiled layouts (``{4,1,3,2,0:T(8,128)}``)
+    dropped so that the simulator's parser reads its instructions."""
+    return parse_hlo_module(re.sub(r"\{[\d,]*:[^{}]*\}", "", text))
+
+
+def _large_kv_cache_ops(mod, min_elems, one_position):
+    """Ops under the ``kv_cache`` scope whose output holds at least
+    ``min_elems`` elements, less the in-place writes: a dynamic-update-slice
+    (or a fusion whose root is one) whose update is ``one_position``
+    elements.  A fusion counts once, its fused ops not at all."""
+    def writes_one_position(comp, op):
+        if op.opcode == "fusion":
+            called = mod.comp(re.search(r"calls=%?([\w.\-]+)", op.raw).group(1))
+            return writes_one_position(called, called.by_name[called.root])
+        return (op.opcode == "dynamic-update-slice"
+                and sum(s.elems for s in mod.op_shape(comp, op.operands[1]))
+                == one_position)
+
+    fused = {m.group(1) for comp in mod.computations.values()
+             for op in comp.ops if op.opcode == "fusion"
+             for m in [re.search(r"calls=%?([\w.\-]+)", op.raw)]}
+    return [op.name for name, comp in mod.computations.items()
+            if name not in fused for op in comp.ops
+            if "/kv_cache/" in op.raw and op.out_elems >= min_elems
+            and not writes_one_position(comp, op)]
+
+
+def test_qwen_decode_holds_its_kv_cache_in_place(one_chip):
+    """At the benchmark cell's shapes (batch 16, 512 positions) the decode
+    step writes one position per layer into the stacked cache and reads the
+    layer's K/V where they lie, in the layout the compiler chose for the
+    loop: no layer copies its slice out or back, and the whole cache is
+    never relaid out, at any step."""
+    cfg = C.get("qwen1.5-4b").full
+    b, S, kv, hd = 16, 512, cfg.num_kv_heads, cfg.resolved_head_dim
+    rc = C.RunConfig(model=cfg, shape=C.ShapeConfig("serve", S, b, "decode"),
+                     mesh=C.SMOKE_MESH)
+    bundle = decode_bundle(rc)
+    args = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip),
+                        bundle.abstract_inputs)
+    compiled = bundle.jit().lower(*args).compile()
+    mod = _parse_tpu_hlo(compiled.as_text())
+
+    large = _large_kv_cache_ops(mod, b * S * kv * hd, b * kv * hd)
+    assert len(large) == 0, f"{len(large)} slice-sized kv_cache ops: {large}"
+    cache_dims = (cfg.num_layers, b, S, kv, hd)
+    whole_copies = [op.name for comp in mod.computations.values()
+                    for op in comp.ops
+                    if op.opcode == "copy" and any(s.dims == cache_dims
+                                                   for s in op.outputs)]
+    assert len(whole_copies) == 0, f"whole-cache copies: {whole_copies}"
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+    (_, cache_in, _), _ = compiled.input_formats
+    _, cache_out = compiled.output_formats
+    for leaf in ("k", "v"):
+        assert cache_in[leaf] == cache_out[leaf]
 
 
 def test_lenet_train_step_compiles(topo):
