@@ -7,6 +7,11 @@ with a chunked-parallel training path (scan over chunks, matmuls within) and a
 recurrent O(1)-state decode path.  Data-dependent token-shift (ddlerp) and the
 decay LoRA follow arXiv:2404.05892; LayerNorms are replaced by RMSNorm for
 uniformity with the rest of the zoo (documented in DESIGN.md).
+
+Ops run under flat ``jax.named_scope``s, as in ``transformer.py``:
+``time_mix`` (token shift, projections, decay LoRA, ``ln_x``, gate and
+output), ``wkv`` (the recurrence alone: the chunk scan, or the decode
+step's read, bonus, decay and update of the state) and ``channel_mix``.
 """
 from __future__ import annotations
 
@@ -89,10 +94,18 @@ def wkv_chunked(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
 
     r,k,v: (b, s, h, hd); logw: (b, s, h, hd) (log decay, <0); u: (h, hd)
     state0: (b, h, hd, hd)  [k-dim x v-dim]
+
+    A length that ``chunk`` does not divide is padded at the tail with
+    k = v = 0 and log w = 0, which leave the state as it is; the padded
+    positions' outputs are dropped.
     """
     b, s, h, hd = r.shape
-    nc = max(s // chunk, 1)
-    c = s // nc
+    c = min(chunk, s)
+    nc = -(-s // c)
+    pad = nc * c - s
+    if pad:
+        r, k, v, logw = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                         for t in (r, k, v, logw))
     rs = lambda t: t.reshape(b, nc, c, h, hd).swapaxes(0, 1)
     r_c, k_c, v_c, w_c = rs(r), rs(k), rs(v), rs(logw)
 
@@ -125,7 +138,7 @@ def wkv_chunked(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
     # 2x2048 tokens at rwkv6-1.6b widths, more than a v5e can spare)
     state, ys = jax.lax.scan(jax.checkpoint(step), state0.astype(jnp.float32),
                              (r_c, k_c, v_c, w_c))
-    y = ys.swapaxes(0, 1).reshape(b, s, h, hd)
+    y = ys.swapaxes(0, 1).reshape(b, nc * c, h, hd)[:, :s]
     return y.astype(r.dtype), state
 
 
@@ -143,19 +156,22 @@ def rwkv_time_mix(params: Dict, cfg: ModelConfig, x: jax.Array,
     """Train/prefill path. Returns (y, last_x, final_state)."""
     heads, hd = _dims(cfg)
     b, s, d = x.shape
-    xs = _shift(x, prev)
-    mixed = _ddlerp(params, x, xs)
     hx = ("batch", None, "heads", None)
-    r = lc(jnp.einsum("bsd,de->bse", mixed["r"], params["wr"]).reshape(b, s, heads, hd), hx)
-    k = lc(jnp.einsum("bsd,de->bse", mixed["k"], params["wk"]).reshape(b, s, heads, hd), hx)
-    v = lc(jnp.einsum("bsd,de->bse", mixed["v"], params["wv"]).reshape(b, s, heads, hd), hx)
-    g = jax.nn.silu(jnp.einsum("bsd,de->bse", mixed["g"], params["wg"]))
-    logw = lc(_decay_log(params, mixed["w"]).reshape(b, s, heads, hd), hx)
-    u = params["u"].astype(jnp.float32).reshape(heads, hd)
-    y, state = wkv_chunked(r, k, v, logw, u, state0)
-    y = lc(y, hx)
-    y = rms_norm(y.reshape(b, s, d), params["ln_x"], cfg.norm_eps) * g
-    out = jnp.einsum("bsd,de->bse", y, params["wo"])
+    with jax.named_scope("time_mix"):
+        xs = _shift(x, prev)
+        mixed = _ddlerp(params, x, xs)
+        r = lc(jnp.einsum("bsd,de->bse", mixed["r"], params["wr"]).reshape(b, s, heads, hd), hx)
+        k = lc(jnp.einsum("bsd,de->bse", mixed["k"], params["wk"]).reshape(b, s, heads, hd), hx)
+        v = lc(jnp.einsum("bsd,de->bse", mixed["v"], params["wv"]).reshape(b, s, heads, hd), hx)
+        g = jax.nn.silu(jnp.einsum("bsd,de->bse", mixed["g"], params["wg"]))
+        logw = lc(_decay_log(params, mixed["w"]).reshape(b, s, heads, hd), hx)
+        u = params["u"].astype(jnp.float32).reshape(heads, hd)
+    with jax.named_scope("wkv"):
+        y, state = wkv_chunked(r, k, v, logw, u, state0)
+    with jax.named_scope("time_mix"):
+        y = lc(y, hx)
+        y = rms_norm(y.reshape(b, s, d), params["ln_x"], cfg.norm_eps) * g
+        out = jnp.einsum("bsd,de->bse", y, params["wo"])
     return out, x[:, -1:], state
 
 
@@ -165,31 +181,35 @@ def rwkv_time_decode(params: Dict, cfg: ModelConfig, x: jax.Array,
     """One-token recurrent step. x: (b,1,d); state: (b,h,hd,hd) fp32."""
     heads, hd = _dims(cfg)
     b, _, d = x.shape
-    mixed = _ddlerp(params, x, prev)
-    r = jnp.einsum("bsd,de->bse", mixed["r"], params["wr"]).reshape(b, heads, hd)
-    k = jnp.einsum("bsd,de->bse", mixed["k"], params["wk"]).reshape(b, heads, hd)
-    v = jnp.einsum("bsd,de->bse", mixed["v"], params["wv"]).reshape(b, heads, hd)
-    g = jax.nn.silu(jnp.einsum("bsd,de->bse", mixed["g"], params["wg"]))
-    logw = _decay_log(params, mixed["w"]).reshape(b, heads, hd)
-    u = params["u"].astype(jnp.float32).reshape(heads, hd)
-    rf, kf, vf = (t.astype(jnp.float32) for t in (r, k, v))
-    y = jnp.einsum("bhd,bhde->bhe", rf, state) + jnp.einsum(
-        "bhd,hd,bhd,bhe->bhe", rf, u, kf, vf)
-    state = state * jnp.exp(logw)[..., None] + jnp.einsum("bhd,bhe->bhde", kf, vf)
-    y = y.reshape(b, 1, d).astype(x.dtype)
-    y = rms_norm(y, params["ln_x"], cfg.norm_eps) * g
-    return jnp.einsum("bsd,de->bse", y, params["wo"]), x, state
+    with jax.named_scope("time_mix"):
+        mixed = _ddlerp(params, x, prev)
+        r = jnp.einsum("bsd,de->bse", mixed["r"], params["wr"]).reshape(b, heads, hd)
+        k = jnp.einsum("bsd,de->bse", mixed["k"], params["wk"]).reshape(b, heads, hd)
+        v = jnp.einsum("bsd,de->bse", mixed["v"], params["wv"]).reshape(b, heads, hd)
+        g = jax.nn.silu(jnp.einsum("bsd,de->bse", mixed["g"], params["wg"]))
+        logw = _decay_log(params, mixed["w"]).reshape(b, heads, hd)
+        u = params["u"].astype(jnp.float32).reshape(heads, hd)
+    with jax.named_scope("wkv"):
+        rf, kf, vf = (t.astype(jnp.float32) for t in (r, k, v))
+        y = jnp.einsum("bhd,bhde->bhe", rf, state) + jnp.einsum(
+            "bhd,hd,bhd,bhe->bhe", rf, u, kf, vf)
+        state = state * jnp.exp(logw)[..., None] + jnp.einsum("bhd,bhe->bhde", kf, vf)
+    with jax.named_scope("time_mix"):
+        y = y.reshape(b, 1, d).astype(x.dtype)
+        y = rms_norm(y, params["ln_x"], cfg.norm_eps) * g
+        return jnp.einsum("bsd,de->bse", y, params["wo"]), x, state
 
 
 def rwkv_channel_mix(params: Dict, cfg: ModelConfig, x: jax.Array,
                      prev: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    xs = _shift(x, prev)
-    xk = x + (xs - x) * params["mu_k"].astype(x.dtype)
-    xr = x + (xs - x) * params["mu_r"].astype(x.dtype)
-    k = jnp.square(jax.nn.relu(jnp.einsum("bsd,df->bsf", xk, params["wk"])))
-    kv = jnp.einsum("bsf,fd->bsd", k, params["wv"])
-    r = jax.nn.sigmoid(jnp.einsum("bsd,de->bse", xr, params["wr"]))
-    return r * kv, x[:, -1:]
+    with jax.named_scope("channel_mix"):
+        xs = _shift(x, prev)
+        xk = x + (xs - x) * params["mu_k"].astype(x.dtype)
+        xr = x + (xs - x) * params["mu_r"].astype(x.dtype)
+        k = jnp.square(jax.nn.relu(jnp.einsum("bsd,df->bsf", xk, params["wk"])))
+        kv = jnp.einsum("bsf,fd->bsd", k, params["wv"])
+        r = jax.nn.sigmoid(jnp.einsum("bsd,de->bse", xr, params["wr"]))
+        return r * kv, x[:, -1:]
 
 
 def rwkv_channel_decode(params: Dict, cfg: ModelConfig, x: jax.Array,

@@ -1,4 +1,9 @@
-"""RWKV6 LM (family "ssm"): attention-free, O(1)-state decode."""
+"""RWKV6 LM (family "ssm"): attention-free, O(1)-state decode.
+
+The embedding, the norms and the output head run under the flat
+``jax.named_scope``s ``embed``, ``norm`` and ``head``; the blocks' own
+scopes (``time_mix``, ``wkv``, ``channel_mix``) are in ``rwkv.py``.
+"""
 from __future__ import annotations
 
 from typing import Any, Dict, Tuple
@@ -60,38 +65,51 @@ class RWKVLM:
     def logical_overrides(self, mesh_cfg: MeshConfig) -> Dict[str, Any]:
         return {}
 
+    def _embed(self, params, tokens):
+        """The tokens' rows of the table, then the input norm ``ln_in``."""
+        cfg = self.cfg
+        with jax.named_scope("embed"):
+            tbl = lc(params["embed"], (None, "embed_tbl"))
+            x = jnp.take(tbl, tokens, axis=0).astype(jnp.dtype(cfg.dtype))
+        with jax.named_scope("norm"):
+            return rms_norm(x, params["ln_in"], cfg.norm_eps)
+
     # ----------------------------------------------------------------- train
     def hidden(self, params, tokens):
         cfg = self.cfg
         b, s = tokens.shape
         heads, hd = rwkv._dims(cfg)
-        x = jnp.take(lc(params["embed"], (None, "embed_tbl")), tokens, axis=0).astype(jnp.dtype(cfg.dtype))
-        x = rms_norm(x, params["ln_in"], cfg.norm_eps)
+        x = self._embed(params, tokens)
         x = lc(x, ("batch", "act_seq", "embed"))
         zeros_prev = jnp.zeros((b, 1, cfg.d_model), x.dtype)
         state0 = jnp.zeros((b, heads, hd, hd), jnp.float32)
 
         def layer(x, p_l):
-            h = rms_norm(x, p_l["ln1"], cfg.norm_eps)
+            with jax.named_scope("norm"):
+                h = rms_norm(x, p_l["ln1"], cfg.norm_eps)
             y, _, _ = rwkv.rwkv_time_mix(p_l["time"], cfg, h, zeros_prev, state0)
             x = x + y
-            h = rms_norm(x, p_l["ln2"], cfg.norm_eps)
+            with jax.named_scope("norm"):
+                h = rms_norm(x, p_l["ln2"], cfg.norm_eps)
             y, _ = rwkv.rwkv_channel_mix(p_l["channel"], cfg, h, zeros_prev)
             return lc(x + y, ("batch", "act_seq", "embed")), None
 
         x, _ = jax.lax.scan(_remat(layer, self.sharding.remat_policy),
                             x, params["layers"])
-        return rms_norm(x, params["ln_f"], cfg.norm_eps)
+        with jax.named_scope("norm"):
+            return rms_norm(x, params["ln_f"], cfg.norm_eps)
 
     def forward(self, params, tokens):
         x = self.hidden(params, tokens)
-        logits = jnp.einsum("bsd,dv->bsv", x, params["head"])
+        with jax.named_scope("head"):
+            logits = jnp.einsum("bsd,dv->bsv", x, params["head"])
         return lc(logits, ("batch", "act_seq", "vocab"))
 
     def loss(self, params, batch):
         x = self.hidden(params, batch["tokens"])
-        loss, ce = lm_loss_from_hidden(x, params["head"], batch["labels"],
-                                       z_loss=1e-4)
+        with jax.named_scope("head"):
+            loss, ce = lm_loss_from_hidden(x, params["head"], batch["labels"],
+                                           z_loss=1e-4)
         return loss, {"ce": ce}
 
     # --------------------------------------------------------------- serving
@@ -100,49 +118,54 @@ class RWKVLM:
         tokens = batch["tokens"]
         b, s = tokens.shape
         heads, hd = rwkv._dims(cfg)
-        x = jnp.take(lc(params["embed"], (None, "embed_tbl")), tokens, axis=0).astype(jnp.dtype(cfg.dtype))
-        x = rms_norm(x, params["ln_in"], cfg.norm_eps)
+        x = self._embed(params, tokens)
         zeros_prev = jnp.zeros((b, 1, cfg.d_model), x.dtype)
         state0 = jnp.zeros((b, heads, hd, hd), jnp.float32)
 
         def layer(x, p_l):
-            h = rms_norm(x, p_l["ln1"], cfg.norm_eps)
+            with jax.named_scope("norm"):
+                h = rms_norm(x, p_l["ln1"], cfg.norm_eps)
             y, tm_prev, state = rwkv.rwkv_time_mix(p_l["time"], cfg, h,
                                                    zeros_prev, state0)
             x = x + y
-            h2 = rms_norm(x, p_l["ln2"], cfg.norm_eps)
+            with jax.named_scope("norm"):
+                h2 = rms_norm(x, p_l["ln2"], cfg.norm_eps)
             y, cm_prev = rwkv.rwkv_channel_mix(p_l["channel"], cfg, h2, zeros_prev)
             cache = {"state": state, "tm_prev": tm_prev, "cm_prev": cm_prev}
             return x + y, cache
 
         x, caches = jax.lax.scan(layer, x, params["layers"])
-        x = rms_norm(x[:, -1:], params["ln_f"], cfg.norm_eps)
-        logits = jnp.einsum("bsd,dv->bsv", x, params["head"])
+        with jax.named_scope("norm"):
+            x = rms_norm(x[:, -1:], params["ln_f"], cfg.norm_eps)
+        with jax.named_scope("head"):
+            logits = jnp.einsum("bsd,dv->bsv", x, params["head"])
         caches["pos"] = jnp.asarray(s, jnp.int32)
         return logits, caches
 
     def decode_step(self, params, cache, batch):
         cfg = self.cfg
         pos = cache["pos"]
-        x = jnp.take(params["embed"], batch["token"], axis=0).astype(
-            jnp.dtype(cfg.dtype))
-        x = rms_norm(x, params["ln_in"], cfg.norm_eps)
+        x = self._embed(params, batch["token"])
 
         def layer(x, inp):
             p_l, st, tm_prev, cm_prev = inp
-            h = rms_norm(x, p_l["ln1"], cfg.norm_eps)
+            with jax.named_scope("norm"):
+                h = rms_norm(x, p_l["ln1"], cfg.norm_eps)
             y, tm_new, st_new = rwkv.rwkv_time_decode(p_l["time"], cfg, h,
                                                       tm_prev, st)
             x = x + y
-            h2 = rms_norm(x, p_l["ln2"], cfg.norm_eps)
+            with jax.named_scope("norm"):
+                h2 = rms_norm(x, p_l["ln2"], cfg.norm_eps)
             y, cm_new = rwkv.rwkv_channel_decode(p_l["channel"], cfg, h2, cm_prev)
             return x + y, {"state": st_new, "tm_prev": tm_new, "cm_prev": cm_new}
 
         x, new_caches = jax.lax.scan(
             layer, x, (params["layers"], cache["state"],
                        cache["tm_prev"], cache["cm_prev"]))
-        x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-        logits = jnp.einsum("bsd,dv->bsv", x, params["head"])
+        with jax.named_scope("norm"):
+            x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+        with jax.named_scope("head"):
+            logits = jnp.einsum("bsd,dv->bsv", x, params["head"])
         new_caches["pos"] = pos + 1
         return logits, new_caches
 

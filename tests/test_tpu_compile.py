@@ -1,5 +1,5 @@
-"""Compile the main path's kernels and the qwen1.5-4b decode step for a
-described TPU v5e, with no chip attached.
+"""Compile the main path's kernels, the qwen1.5-4b decode step and the
+rwkv6-1.6b serve steps for a described TPU v5e, with no chip attached.
 
 The TPU compiler refuses what interpret mode accepts (unaligned slices, too
 much VMEM, dots with several batch dimensions) and programs that do not fit
@@ -21,7 +21,7 @@ from repro.core.hlo_ir import parse_hlo_module
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
 from repro.kernels.tiled_matmul.kernel import tiled_matmul
 from repro.kernels.winograd.kernel import winograd_tiles
-from repro.runtime.steps import decode_bundle, train_bundle
+from repro.runtime.steps import decode_bundle, prefill_bundle, train_bundle
 
 V5E_HBM_BYTES = 16 * 2**30
 
@@ -176,3 +176,52 @@ def test_sharded_dense_train_step_compiles_without_options(topo):
     assert bundle.compiler_options is None
     compiled = bundle.lower(mesh).compile()
     assert "all-gather" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def rwkv_serve_step(one_chip):
+    """The rwkv6-1.6b decode cell's steps at published widths, compiled once
+    each: the prefill of 256 prompts of 256 tokens and the decode step at
+    batch 256."""
+    compiled = {}
+
+    def get(kind):
+        if kind not in compiled:
+            cfg = C.get("rwkv6-1.6b").full
+            rc = C.RunConfig(model=cfg, shape=C.ShapeConfig("serve", 256, 256, kind),
+                             mesh=C.SMOKE_MESH)
+            bundle = (prefill_bundle if kind == "prefill" else decode_bundle)(rc)
+            args = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip),
+                                bundle.abstract_inputs)
+            compiled[kind] = bundle.jit().lower(*args).compile()
+        return compiled[kind]
+    return get
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_rwkv_serve_steps_fit_one_chip(rwkv_serve_step, kind):
+    """The rwkv6-1.6b decode cell's steps at published widths: the prefill
+    of 256 prompts of 256 tokens and the decode step at batch 256, whose
+    float32 WKV state is 3.2e9 bytes.  What each holds at once (arguments,
+    outputs and temp, less what the outputs reuse) fits the chip."""
+    mem = rwkv_serve_step(kind).memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert mem.argument_size_in_bytes > 3 * 10**9          # the weights, at least
+    assert live < V5E_HBM_BYTES
+
+
+def test_rwkv_decode_step_keeps_its_state_in_float32(rwkv_serve_step):
+    """The configuration's float32 WKV state, as the TPU compiler builds the
+    decode step: every op of the ``wkv`` scope that makes an array of the
+    state's shape makes float32, and a contraction there that the compiler
+    puts on the MXU (where float32 operands at the default precision take
+    one bfloat16 pass) asks for ``highest``.  Today the read r.S, the bonus
+    and the update are float32 multiplies and sums on the vector unit."""
+    state = "[256,32,64,64]"
+    ops = [ln for ln in rwkv_serve_step("decode").as_text().splitlines()
+           if "/wkv/" in ln and " = " in ln]
+    shaped = [ln for ln in ops if state in ln.split(" = ", 1)[1].split("(", 1)[0]]
+    assert shaped and all(re.search(r"= f32\[256,32,64,64\]", ln) for ln in shaped)
+    mxu = [ln for ln in ops if re.search(r" (convolution|dot)\(", ln)]
+    assert all("operand_precision={highest" in ln for ln in mxu), mxu[:1]
