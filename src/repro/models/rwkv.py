@@ -178,7 +178,10 @@ def rwkv_time_mix(params: Dict, cfg: ModelConfig, x: jax.Array,
 def rwkv_time_decode(params: Dict, cfg: ModelConfig, x: jax.Array,
                      prev: jax.Array, state: jax.Array
                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One-token recurrent step. x: (b,1,d); state: (b,h,hd,hd) fp32."""
+    """One-token recurrent step. x: (b,1,d); state: (..., b,h,hd,hd) fp32.
+
+    Leading dims of ``state`` (the decode loop passes its layer's slice as
+    (1, b,h,hd,hd)) ride through the read and the update unchanged."""
     heads, hd = _dims(cfg)
     b, _, d = x.shape
     with jax.named_scope("time_mix"):
@@ -191,7 +194,7 @@ def rwkv_time_decode(params: Dict, cfg: ModelConfig, x: jax.Array,
         u = params["u"].astype(jnp.float32).reshape(heads, hd)
     with jax.named_scope("wkv"):
         rf, kf, vf = (t.astype(jnp.float32) for t in (r, k, v))
-        y = jnp.einsum("bhd,bhde->bhe", rf, state) + jnp.einsum(
+        y = jnp.einsum("bhd,...bhde->...bhe", rf, state) + jnp.einsum(
             "bhd,hd,bhd,bhe->bhe", rf, u, kf, vf)
         state = state * jnp.exp(logw)[..., None] + jnp.einsum("bhd,bhe->bhde", kf, vf)
     with jax.named_scope("time_mix"):

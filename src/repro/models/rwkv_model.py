@@ -1,5 +1,11 @@
 """RWKV6 LM (family "ssm"): attention-free, O(1)-state decode.
 
+Decode carries the stacked caches (the float32 WKV state, the two
+token-shift carries) through its layer loop and writes each layer's slice
+in place, as the transformer does its KV cache: the state is most of the
+bytes a decode step moves, and the loop then holds it once and never
+copies it.
+
 The embedding, the norms and the output head run under the flat
 ``jax.named_scope``s ``embed``, ``norm`` and ``head``; the blocks' own
 scopes (``time_mix``, ``wkv``, ``channel_mix``) are in ``rwkv.py``.
@@ -143,12 +149,27 @@ class RWKVLM:
         return logits, caches
 
     def decode_step(self, params, cache, batch):
+        """batch: {"token": (b, 1) int32}. Returns (logits, new cache).
+
+        The stacked caches are the layer loop's CARRY: layer ``idx`` reads
+        its slice where it lies and writes the new one back in place.  The
+        while loop aliases carries, so the float32 WKV state (3.2 GB at
+        batch 256) exists once in HBM; as the scan's ``xs``/``ys`` every
+        layer sliced it out, relaid it out and back, and wrote it into a
+        second stack, and a whole-state copy followed the loop.  The state's
+        slice keeps its layer axis of 1 from read to write: with a reshape
+        between them, the TPU compiler copies the slice out before its
+        in-place update."""
         cfg = self.cfg
         pos = cache["pos"]
         x = self._embed(params, batch["token"])
 
-        def layer(x, inp):
-            p_l, st, tm_prev, cm_prev = inp
+        def layer(carry, inp):
+            x, st_all, tm_all, cm_all = carry
+            p_l, idx = inp
+            st = jax.lax.dynamic_slice_in_dim(st_all, idx, 1, 0)
+            tm_prev = jax.lax.dynamic_index_in_dim(tm_all, idx, 0, keepdims=False)
+            cm_prev = jax.lax.dynamic_index_in_dim(cm_all, idx, 0, keepdims=False)
             with jax.named_scope("norm"):
                 h = rms_norm(x, p_l["ln1"], cfg.norm_eps)
             y, tm_new, st_new = rwkv.rwkv_time_decode(p_l["time"], cfg, h,
@@ -157,17 +178,21 @@ class RWKVLM:
             with jax.named_scope("norm"):
                 h2 = rms_norm(x, p_l["ln2"], cfg.norm_eps)
             y, cm_new = rwkv.rwkv_channel_decode(p_l["channel"], cfg, h2, cm_prev)
-            return x + y, {"state": st_new, "tm_prev": tm_new, "cm_prev": cm_new}
+            st_all = jax.lax.dynamic_update_slice_in_dim(st_all, st_new, idx, 0)
+            tm_all = jax.lax.dynamic_update_index_in_dim(tm_all, tm_new, idx, 0)
+            cm_all = jax.lax.dynamic_update_index_in_dim(cm_all, cm_new, idx, 0)
+            return (x + y, st_all, tm_all, cm_all), None
 
-        x, new_caches = jax.lax.scan(
-            layer, x, (params["layers"], cache["state"],
-                       cache["tm_prev"], cache["cm_prev"]))
+        idxs = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+        (x, state, tm_prev, cm_prev), _ = jax.lax.scan(
+            layer, (x, cache["state"], cache["tm_prev"], cache["cm_prev"]),
+            (params["layers"], idxs))
         with jax.named_scope("norm"):
             x = rms_norm(x, params["ln_f"], cfg.norm_eps)
         with jax.named_scope("head"):
             logits = jnp.einsum("bsd,dv->bsv", x, params["head"])
-        new_caches["pos"] = pos + 1
-        return logits, new_caches
+        return logits, {"state": state, "tm_prev": tm_prev, "cm_prev": cm_prev,
+                        "pos": pos + 1}
 
     # ------------------------------------------------------------------ specs
     def text_len(self, shape: ShapeConfig) -> int:

@@ -1,5 +1,7 @@
 """Per-architecture smoke tests (reduced configs, deliverable f) +
 prefill/decode consistency against the full forward."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -91,6 +93,61 @@ def test_prefill_decode_matches_forward(arch):
     # chunked-vs-recurrent reassociation allows small drift for SSM/hybrid
     tol = 0.05 if cfg.family in ("hybrid", "ssm") else 1e-3
     assert rel < tol, f"{arch}: decode/forward rel err {rel}"
+
+
+def _rwkv_decode_step_as_scan_xs(model, params, cache, batch):
+    """The rwkv6 decode step with its stacked caches as the layer scan's
+    ``xs`` and the new caches as its ``ys``: the loop that carrying the
+    caches in place replaced."""
+    from repro.models import rwkv
+    from repro.models.layers import rms_norm
+    cfg = model.cfg
+    x = model._embed(params, batch["token"])
+
+    def layer(x, inp):
+        p_l, st, tm_prev, cm_prev = inp
+        h = rms_norm(x, p_l["ln1"], cfg.norm_eps)
+        y, tm_new, st_new = rwkv.rwkv_time_decode(p_l["time"], cfg, h, tm_prev, st)
+        x = x + y
+        h2 = rms_norm(x, p_l["ln2"], cfg.norm_eps)
+        y, cm_new = rwkv.rwkv_channel_decode(p_l["channel"], cfg, h2, cm_prev)
+        return x + y, {"state": st_new, "tm_prev": tm_new, "cm_prev": cm_new}
+
+    x, new = jax.lax.scan(layer, x, (params["layers"], cache["state"],
+                                     cache["tm_prev"], cache["cm_prev"]))
+    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+    new["pos"] = cache["pos"] + 1
+    return jnp.einsum("bsd,dv->bsv", x, params["head"]), new
+
+
+def test_rwkv_decode_in_place_matches_scan_over_caches():
+    """rwkv6's decode step, which carries its stacked caches through the
+    layer loop and writes each layer's slice in place, gives the logits and
+    caches of the loop that took the caches as ``xs`` and gave them back as
+    ``ys``, over three steps after a prefill, in the same tree, shapes and
+    dtypes."""
+    cfg = C.get("rwkv6-1.6b").smoke
+    model = build_model(cfg)
+    params = model.init(jax.random.key(0))
+    tokens = jax.random.randint(jax.random.key(1), (2, 12), 0, cfg.vocab_size)
+    _, cache = jax.jit(model.prefill)(params, {"tokens": tokens[:, :9]})
+    new_step = jax.jit(model.decode_step)
+    old_step = jax.jit(functools.partial(_rwkv_decode_step_as_scan_xs, model))
+    got = want = cache
+    for t in range(9, 12):
+        batch = {"token": tokens[:, t:t + 1]}
+        logits, got = new_step(params, got, batch)
+        ref_logits, want = old_step(params, want, batch)
+        assert (jax.tree.structure(got) == jax.tree.structure(want)
+                == jax.tree.structure(cache))
+        for a, b, c in zip(jax.tree.leaves((logits, got)),
+                           jax.tree.leaves((ref_logits, want)),
+                           jax.tree.leaves((ref_logits, cache))):
+            assert a.shape == b.shape == c.shape and a.dtype == b.dtype == c.dtype
+            np.testing.assert_allclose(np.asarray(a, np.float32),
+                                       np.asarray(b, np.float32),
+                                       rtol=1e-5, atol=1e-6)
+    assert got["state"].dtype == jnp.float32 and int(got["pos"]) == 12
 
 
 def test_moe_nodrop_consistency():

@@ -95,6 +95,15 @@ def _parse_tpu_hlo(text):
     return parse_hlo_module(re.sub(r"\{[\d,]*:[^{}]*\}", "", text))
 
 
+def _top_level(mod):
+    """The module's computations that no fusion calls, by name."""
+    fused = {m.group(1) for comp in mod.computations.values()
+             for op in comp.ops if op.opcode == "fusion"
+             for m in [re.search(r"calls=%?([\w.\-]+)", op.raw)]}
+    return {name: comp for name, comp in mod.computations.items()
+            if name not in fused}
+
+
 def _large_kv_cache_ops(mod, min_elems, one_position):
     """Ops under the ``kv_cache`` scope whose output holds at least
     ``min_elems`` elements, less the in-place writes: a dynamic-update-slice
@@ -108,11 +117,7 @@ def _large_kv_cache_ops(mod, min_elems, one_position):
                 and sum(s.elems for s in mod.op_shape(comp, op.operands[1]))
                 == one_position)
 
-    fused = {m.group(1) for comp in mod.computations.values()
-             for op in comp.ops if op.opcode == "fusion"
-             for m in [re.search(r"calls=%?([\w.\-]+)", op.raw)]}
-    return [op.name for name, comp in mod.computations.items()
-            if name not in fused for op in comp.ops
+    return [op.name for comp in _top_level(mod).values() for op in comp.ops
             if "/kv_cache/" in op.raw and op.out_elems >= min_elems
             and not writes_one_position(comp, op)]
 
@@ -146,6 +151,31 @@ def test_qwen_decode_holds_its_kv_cache_in_place(one_chip):
     _, cache_out = compiled.output_formats
     for leaf in ("k", "v"):
         assert cache_in[leaf] == cache_out[leaf]
+
+
+def test_rwkv_decode_holds_its_state_in_place(rwkv_serve_step):
+    """The rwkv6 decode cell's step (batch 256, float32 WKV state of
+    (24, 256, 32, 64, 64)) updates each layer's slice of the stacked state
+    where it lies: no op outside a fusion makes an array of a layer's slice
+    (no slice copied out, relaid out or stacked anew), nothing copies the
+    whole state, the state keeps one layout from input to output, and the
+    step's temp holds no second state."""
+    compiled = rwkv_serve_step("decode")
+    mod = _parse_tpu_hlo(compiled.as_text())
+    stack = (24, 256, 32, 64, 64)
+    state_dims = {stack, stack[1:], (1,) + stack[1:]}
+    made = [op for comp in _top_level(mod).values() for op in comp.ops
+            if op.opcode not in ("parameter", "get-tuple-element", "tuple", "while")
+            and any(s.dims in state_dims for s in op.outputs)]
+    copies = [op.name for op in made if op.opcode == "copy"]
+    assert not copies, f"state-sized copies: {copies}"
+    slices = [op.name for op in made
+              if any(s.dims in state_dims - {stack} for s in op.outputs)]
+    assert not slices, f"ops that make a layer's state slice: {slices}"
+    (_, cache_in, _), _ = compiled.input_formats
+    _, cache_out = compiled.output_formats
+    assert cache_in["state"] == cache_out["state"]
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2**20
 
 
 def test_lenet_train_step_compiles(topo):
