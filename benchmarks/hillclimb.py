@@ -97,8 +97,6 @@ def apply_variant(rc: C.RunConfig, variant: str) -> C.RunConfig:
             sh = dataclasses.replace(sh, sequence_sharding=False)
         elif f == "nofsdp":
             sh = dataclasses.replace(sh, fsdp=False)
-        elif f == "bf16norm":
-            sh = dataclasses.replace(sh, bf16_norm_apply=True)
         elif f == "noep":
             sh = dataclasses.replace(sh, expert_parallel=False)
         elif f.startswith("accum"):

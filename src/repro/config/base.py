@@ -200,14 +200,9 @@ class ShardingConfig:
     fsdp: bool = True                 # shard params/opt-state over the data axis too
     sequence_sharding: bool = True    # Megatron-SP residual stream over model axis
     shard_embed_over: str = "model"   # embedding table: partition d_model or vocab
-    sequence_parallel_decode: bool = False  # SP for long-context decode KV/state
     expert_parallel: bool = True      # shard MoE experts over model axis
     remat_policy: str = "full"        # "none" | "full" | "dots" (checkpoint policy)
-    scan_layers: bool = True          # lax.scan over stacked layer params
-    gradient_compression: str = "none"  # "none" | "int8"
     moe_gather_once: bool = False     # explicit seq all-gather before dispatch
-    bf16_norm_apply: bool = False     # fp32 stats, bf16 scale-apply in norms
-    collective_matmul: bool = False   # beyond-paper: overlap AG with matmul
     extra_rules: Tuple[Tuple[str, Any], ...] = ()
 
 

@@ -2,8 +2,9 @@
 
 Models annotate parameters and activations with *logical* axis names
 ("batch", "qkv", "ffn", "experts", ...).  A rule table maps logical names to
-mesh axes; the mapping depends on ShardingConfig (fsdp on/off, SP decode, pod
-role) so one model definition serves every parallelism layout.
+mesh axes; the mapping depends on ShardingConfig (fsdp on/off, sequence
+sharding, expert parallelism) and the pod role, so one model definition serves
+every parallelism layout.
 
 Inside a jit trace, :func:`lc` applies ``with_sharding_constraint`` using the
 ambient rules+mesh installed by :func:`use_rules` (a context manager the step
@@ -42,7 +43,7 @@ def logical_rules(mesh_cfg: MeshConfig, sharding: ShardingConfig) -> Rules:
         # blocks (AG on block entry / RS on block exit) — divides saved-for-
         # backward activation memory by the model-axis size
         "act_seq": "model" if sharding.sequence_sharding else None,
-        "kv_seq": "data" if sharding.sequence_parallel_decode else None,
+        "kv_seq": None,
         "embed": None,                # activation d_model dim stays replicated
         "qkv": "model",               # flattened heads*head_dim activation dim
         "heads": "model",

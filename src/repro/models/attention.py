@@ -191,24 +191,6 @@ def _decode_attend(params: Dict, cfg: ModelConfig, q: jax.Array,
         return dense(out.reshape(b, 1, h * hd), params["wo"])
 
 
-def attention_decode(params: Dict, cfg: ModelConfig, x: jax.Array,
-                     cache_k: jax.Array, cache_v: jax.Array, pos: jax.Array,
-                     *, window: int = 0
-                     ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
-    """One-token decode against a (b, S, kv, hd) cache; returns updated cache.
-
-    ``pos`` is the scalar index of the new token (same for the whole batch).
-    """
-    q, k_new, v_new = _decode_qkv(params, cfg, x, pos)
-    with jax.named_scope("kv_cache"):
-        cache_k = jax.lax.dynamic_update_slice(
-            cache_k, k_new.astype(cache_k.dtype), (0, pos, 0, 0))
-        cache_v = jax.lax.dynamic_update_slice(
-            cache_v, v_new.astype(cache_v.dtype), (0, pos, 0, 0))
-    out = _decode_attend(params, cfg, q, cache_k, cache_v, pos, window)
-    return out, (cache_k, cache_v)
-
-
 def attention_decode_stacked(params: Dict, cfg: ModelConfig, x: jax.Array,
                              cache_k: jax.Array, cache_v: jax.Array,
                              idx: jax.Array, pos: jax.Array, *, window: int = 0
@@ -216,6 +198,7 @@ def attention_decode_stacked(params: Dict, cfg: ModelConfig, x: jax.Array,
     """One-token decode of layer ``idx`` against the stacked (L, b, S, kv, hd)
     cache, in place: the new position is written into the stack and the
     layer's K/V are read where they lie, never copied out and back.
+    ``pos`` is the scalar index of the new token (same for the whole batch).
     Returns the updated stacked cache."""
     q, k_new, v_new = _decode_qkv(params, cfg, x, pos)
     with jax.named_scope("kv_cache"):

@@ -182,12 +182,9 @@ class EncDecLM:
         def layer(carry, inp):
             x, ck_all, cv_all = carry       # cache carried: in-place aliasing
             p_l, idx = inp
-            ck = jax.lax.dynamic_index_in_dim(ck_all, idx, 0, keepdims=False)
-            cv = jax.lax.dynamic_index_in_dim(cv_all, idx, 0, keepdims=False)
             h = rms_norm(x, p_l["ln1"], cfg.norm_eps)
-            h, (ck, cv) = attn.attention_decode(p_l["self_attn"], cfg, h, ck, cv, pos)
-            ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, idx, 0)
-            cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, idx, 0)
+            h, (ck_all, cv_all) = attn.attention_decode_stacked(
+                p_l["self_attn"], cfg, h, ck_all, cv_all, idx, pos)
             x = x + h
             h = rms_norm(x, p_l["ln_x"], cfg.norm_eps)
             h = attn.attention(p_l["cross_attn"], cfg, h, positions,

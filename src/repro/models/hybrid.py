@@ -194,39 +194,61 @@ class HybridLM:
         return logits, cache
 
     def decode_step(self, params, cache, batch):
+        """batch: {"token": (b, 1) int32}. Returns (logits, new cache).
+
+        The stacked caches are the loops' CARRY, updated in place: the shared
+        attention writes its new position into the stacked K/V, and a mamba
+        layer reads its slice of ``state``/``conv`` where it lies and writes
+        the new one back, its layer axes kept at size 1 from read to write
+        (with a reshape between them the TPU compiler copies the slice out)."""
         cfg = self.cfg
         pos = cache["pos"]
         x = jnp.take(params["embed"], batch["token"], axis=0).astype(
             jnp.dtype(cfg.dtype))
         shared = params["shared"]
 
-        def mamba_decode(x, inp):
-            p_l, mc = inp
-            h = rms_norm(x, p_l["ln"], cfg.norm_eps)
-            h, mc = ssm.ssm_decode_step(p_l["mixer"], cfg, h, mc)
-            return x + h, mc
+        def mamba_decode(lead):
+            """The loop body whose j-th step updates layer ``lead + (j,)``."""
+            def body(carry, inp):
+                (x, mc_all), (p_l, j) = carry, inp
+                ones = (1,) * (len(lead) + 1)
+                at = lambda c: lead + (j,) + (0,) * (c.ndim - len(ones))
+                mc = {k: jax.lax.dynamic_slice(c, at(c), ones + c.shape[len(ones):])
+                      for k, c in mc_all.items()}
+                h = rms_norm(x, p_l["ln"], cfg.norm_eps)
+                h, mc = ssm.ssm_decode_step(p_l["mixer"], cfg, h, mc)
+                mc_all = {k: jax.lax.dynamic_update_slice(c, mc[k], at(c))
+                          for k, c in mc_all.items()}
+                return (x + h, mc_all), None
+            return body
 
-        def group_decode(x, inp):
-            p_group, gc = inp
+        def group_decode(carry, inp):
+            (x, ck_all, cv_all, mc_all), (p_group, g) = carry, inp
             h = rms_norm(x, shared["ln1"], cfg.norm_eps)
-            h, (ck, cv) = attn.attention_decode(shared["attn"], cfg, h,
-                                                gc["k"], gc["v"], pos)
+            h, (ck_all, cv_all) = attn.attention_decode_stacked(
+                shared["attn"], cfg, h, ck_all, cv_all, g, pos)
             x = x + h
             h = rms_norm(x, shared["ln2"], cfg.norm_eps)
             x = x + swiglu(h, shared["ffn"]["w_gate"], shared["ffn"]["w_up"],
                            shared["ffn"]["w_down"])
-            x, mcaches = jax.lax.scan(mamba_decode, x, (p_group, gc["mamba"]))
-            return x, {"k": ck, "v": cv, "mamba": mcaches}
+            (x, mc_all), _ = jax.lax.scan(
+                mamba_decode((g,)), (x, mc_all),
+                (p_group, jnp.arange(cfg.attn_every, dtype=jnp.int32)))
+            return (x, ck_all, cv_all, mc_all), None
 
-        x, gcaches = jax.lax.scan(group_decode, x, (params["groups"],
-                                                    cache["groups"]))
+        gc = cache["groups"]
+        (x, ks, vs, mamba), _ = jax.lax.scan(
+            group_decode, (x, gc["k"], gc["v"], gc["mamba"]),
+            (params["groups"], jnp.arange(self.groups, dtype=jnp.int32)))
         tail_cache = cache["tail"]
         if self.remainder:
-            x, tail_cache = jax.lax.scan(mamba_decode, x,
-                                         (params["tail"], cache["tail"]))
+            (x, tail_cache), _ = jax.lax.scan(
+                mamba_decode(()), (x, tail_cache),
+                (params["tail"], jnp.arange(self.remainder, dtype=jnp.int32)))
         x = rms_norm(x, params["ln_f"], cfg.norm_eps)
         logits = jnp.einsum("bsd,dv->bsv", x, params["head"])
-        return logits, {"groups": gcaches, "tail": tail_cache, "pos": pos + 1}
+        return logits, {"groups": {"k": ks, "v": vs, "mamba": mamba},
+                        "tail": tail_cache, "pos": pos + 1}
 
     # ------------------------------------------------------------------ specs
     def text_len(self, shape: ShapeConfig) -> int:

@@ -107,18 +107,9 @@ def stack_specs(specs: Any, n: int, axis_name: str = "layers") -> Any:
 # Layers
 # ---------------------------------------------------------------------------
 
-# trace-time static switch (set from ShardingConfig by the step builders):
-# True = fp32 statistics but bf16 scale application, keeping the bwd
-# residual-stream cotangents bf16 (fp32 cotangents force fp32 all-reduces)
-BF16_NORM_APPLY = False
-
-
 def rms_norm(x: jax.Array, gamma: jax.Array, eps: float = 1e-6) -> jax.Array:
     xf = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-    if BF16_NORM_APPLY:
-        scale = jax.lax.rsqrt(var + eps).astype(x.dtype)
-        return x * scale * (1.0 + gamma.astype(jnp.float32)).astype(x.dtype)
     normed = (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype)
     return normed * (1.0 + gamma.astype(jnp.float32)).astype(x.dtype)
 

@@ -150,26 +150,21 @@ def ssm_mixer(params: Dict, cfg: ModelConfig, x: jax.Array
 # Decode (recurrent, O(1) per token)
 # ---------------------------------------------------------------------------
 
-def ssm_cache_shape(cfg: ModelConfig, batch: int) -> Dict[str, Tuple[int, ...]]:
-    d_inner, heads, headdim, n = _dims(cfg)
-    conv_ch = d_inner + 2 * n
-    return {
-        "state": (batch, heads, headdim, n),
-        "conv": (batch, cfg.ssm_conv - 1, conv_ch),
-    }
-
-
 def ssm_decode_step(params: Dict, cfg: ModelConfig, x: jax.Array,
                     cache: Dict[str, jax.Array]
                     ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """x: (b, 1, d); cache: {state: (b,h,p,n) fp32, conv: (b,w-1,c)}."""
+    """x: (b, 1, d); cache: {state: (...,b,h,p,n) fp32, conv: (...,b,w-1,c)},
+    whose leading axes (a stacked cache's layer axes, of size 1) it keeps."""
     d_inner, heads, headdim, n = _dims(cfg)
     b = x.shape[0]
     zxbcdt = jnp.einsum("bsd,de->bse", x, params["in_proj"])
     z, xs, B, C, dt = _split_proj(cfg, zxbcdt)
     xbc_new = jnp.concatenate([xs, B, C], axis=-1)                   # (b, 1, c)
-    window = jnp.concatenate([cache["conv"], xbc_new], axis=1)       # (b, w, c)
-    conv_out = jnp.sum(window * params["conv_w"].astype(window.dtype)[None], axis=1)
+    conv = cache["conv"]
+    window = jnp.concatenate(                                        # (..., b, w, c)
+        [conv, jnp.broadcast_to(xbc_new, conv.shape[:-3] + xbc_new.shape)], axis=-2)
+    conv_out = jnp.sum(window * params["conv_w"].astype(window.dtype),
+                       axis=-2).reshape(b, -1)
     xbc = jax.nn.silu(conv_out + params["conv_b"].astype(conv_out.dtype))
     xs1, B1, C1 = jnp.split(xbc, [d_inner, d_inner + n], axis=-1)    # (b, c)
     dt1 = jax.nn.softplus(dt[:, 0].astype(jnp.float32) +
@@ -179,10 +174,10 @@ def ssm_decode_step(params: Dict, cfg: ModelConfig, x: jax.Array,
     dA = jnp.exp(dt1 * A)                                            # (b, h)
     state = cache["state"] * dA[..., None, None] + jnp.einsum(
         "bn,bhp->bhpn", B1.astype(jnp.float32), xh * dt1[..., None])
-    y = jnp.einsum("bn,bhpn->bhp", C1.astype(jnp.float32), state)
+    y = jnp.einsum("bn,...bhpn->...bhp", C1.astype(jnp.float32), state)
     y = y + xh * params["d_skip"].astype(jnp.float32)[None, :, None]
     y = y.reshape(b, 1, d_inner).astype(x.dtype)
     y = rms_norm(y * jax.nn.silu(z), params["norm"], cfg.norm_eps)
     out = jnp.einsum("bse,ed->bsd", y, params["out_proj"])
-    new_cache = {"state": state, "conv": window[:, 1:]}
+    new_cache = {"state": state, "conv": window[..., 1:, :]}
     return out, new_cache

@@ -28,7 +28,7 @@ import numpy as np
 from repro.config import RunConfig
 from repro.models import build_model
 from repro.obs.trace import TRACER
-from repro.runtime.steps import decode_bundle, is_kv_leaf, prefill_bundle
+from repro.runtime.steps import decode_bundle, position_axes, prefill_bundle
 
 
 @dataclass
@@ -94,12 +94,13 @@ class CompiledPerShape:
         return self.compiled(*args)(*args)
 
 
-def _pad_positions(x: jax.Array, extra: int) -> jax.Array:
-    """A KV cache leaf (L, b, S, kv, hd) with ``extra`` more positions, grown
-    a layer at a time: a change of layout on the way then needs one
-    layer's room, not the whole leaf's."""
-    return jax.lax.map(
-        lambda xl: jnp.pad(xl, [(0, 0), (0, extra), (0, 0), (0, 0)]), x)
+def _pad_positions(x: jax.Array, axis: int, extra: int) -> jax.Array:
+    """A stacked cache leaf with ``extra`` more positions along ``axis``,
+    grown a layer (its leading axis) at a time: a change of layout on the
+    way then needs one layer's room, not the whole leaf's."""
+    widths = [(0, 0)] * (x.ndim - 1)
+    widths[axis - 1] = (0, extra)
+    return jax.lax.map(lambda xl: jnp.pad(xl, widths), x)
 
 
 class Server:
@@ -116,6 +117,8 @@ class Server:
         self._decode = self._decode_step = CompiledPerShape(
             decode_bundle(run_cfg, mesh).jit())
         self._pads: Dict[Any, Any] = {}
+        self._positions = position_axes(
+            self.model.decode_state_specs(run_cfg.shape)[1])
         self.stats = ServeStats()
 
     def _sample(self, logits: jax.Array, key) -> jax.Array:
@@ -125,14 +128,15 @@ class Server:
         return jax.random.categorical(key, logits / self.temperature).astype(jnp.int32)
 
     def _grow_cache(self, cache, extra: int, batch: int):
-        """Grow the prefill cache's K/V leaves by ``extra`` positions, each
+        """Grow the prefill cache's position axes by ``extra``, each leaf
         straight into the format the decode step takes it in: the cache's
         one relayout, after which every step takes the previous one's cache
         as it is.  Each prefill leaf is freed once grown, so that the last
         leaf grows beside the rest of the grown cache and nothing more."""
-        grown = jax.tree_util.tree_map_with_path(
-            lambda path, x: jax.eval_shape(lambda y: _pad_positions(y, extra), x)
-            if is_kv_leaf(path, x) else x, cache)
+        grown = jax.tree.map(
+            lambda x, axis: x if axis is None
+            else jax.eval_shape(lambda y: _pad_positions(y, axis, extra), x),
+            cache, self._positions)
         tok = jax.ShapeDtypeStruct((batch, 1), jnp.int32)
         exe = self._decode_step.compiled(self.params, grown, {"token": tok})
         (_, formats, _), _ = exe.input_formats
@@ -141,15 +145,15 @@ class Server:
                 f"the decode step takes its cache in {formats} but returns it "
                 f"in {exe.output_formats[1]}: each step would relayout it")
 
-        def grow(path, leaf, fmt):
-            if not is_kv_leaf(path, leaf):
+        def grow(leaf, axis, fmt):
+            if axis is None:
                 return leaf
-            key = (leaf.shape, leaf.dtype, extra, fmt)
+            key = (leaf.shape, leaf.dtype, axis, extra, fmt)
             pad = self._pads.get(key)
             if pad is None:
                 with _uncached_compiles():
-                    pad = jax.jit(_pad_positions, static_argnums=1,
-                                  out_shardings=fmt).lower(leaf, extra).compile()
+                    pad = jax.jit(_pad_positions, static_argnums=(1, 2),
+                                  out_shardings=fmt).lower(leaf, axis, extra).compile()
                 self._pads[key] = pad
             # not donated, as XLA cannot reuse a buffer smaller than the
             # output: freed by hand once the pad is done, before the next
@@ -158,7 +162,7 @@ class Server:
             leaf.delete()
             return out
 
-        return jax.tree_util.tree_map_with_path(grow, cache, formats)
+        return jax.tree.map(grow, cache, self._positions, formats)
 
     def generate(self, batch: Dict[str, Any], max_new_tokens: int = 16,
                  seed: int = 0) -> np.ndarray:

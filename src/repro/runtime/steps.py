@@ -1,7 +1,7 @@
 """Step builders: the single source of truth for train/prefill/decode step
 functions, their abstract input specs and their shardings.
 
-Used by three consumers with identical semantics:
+Used by four consumers with identical semantics:
   * smoke tests      — materialized params, no mesh
   * launch/dryrun.py — ShapeDtypeStructs + NamedShardings on 256/512-chip meshes
   * launch/train.py  — real training on whatever devices exist
@@ -60,18 +60,11 @@ class StepBundle:
                        compiler_options=self.compiler_options, **kw)
 
 
-def _ambient(fn: Callable, rules, mesh, sharding=None) -> Callable:
+def _ambient(fn: Callable, rules, mesh) -> Callable:
     @functools.wraps(fn)
     def wrapped(*args):
-        from repro.models import layers as _layers
-        prev = _layers.BF16_NORM_APPLY
-        if sharding is not None:
-            _layers.BF16_NORM_APPLY = sharding.bf16_norm_apply
-        try:
-            with use_rules(rules, mesh):
-                return fn(*args)
-        finally:
-            _layers.BF16_NORM_APPLY = prev
+        with use_rules(rules, mesh):
+            return fn(*args)
     return wrapped
 
 
@@ -80,7 +73,8 @@ def _rules(run_cfg: RunConfig, model):
     rules.update(model.logical_overrides(run_cfg.mesh))
     mesh_cfg = run_cfg.mesh
     # batch divisibility: long_500k (batch=1) can't shard batch over data —
-    # replicate batch and turn on sequence-parallel caches instead
+    # replicate batch and shard the caches' positions over data instead, the
+    # one place that shards ``kv_seq``
     batch_ax = rules.get("batch")
     if batch_ax is not None:
         axes = (batch_ax,) if isinstance(batch_ax, str) else batch_ax
@@ -145,7 +139,7 @@ def train_bundle(run_cfg: RunConfig, mesh: Optional[Mesh] = None) -> StepBundle:
         metrics["loss"] = loss
         return new_state, metrics
 
-    fn = _ambient(train_step, rules, mesh, run_cfg.sharding)
+    fn = _ambient(train_step, rules, mesh)
     state_sds = abstract_state(model.abstract())
     batch_sds, batch_axes = model.train_input_specs(run_cfg.shape)
     st_axes = state_axes(model.axes())
@@ -201,7 +195,7 @@ def prefill_bundle(run_cfg: RunConfig, mesh: Optional[Mesh] = None) -> StepBundl
     def prefill_step(params, batch):
         return model.prefill(params, batch)
 
-    fn = _ambient(prefill_step, rules, mesh, run_cfg.sharding)
+    fn = _ambient(prefill_step, rules, mesh)
     params_sds = model.abstract()
     batch_sds, batch_axes = model.prefill_input_specs(run_cfg.shape)
     in_sh = out_sh = None
@@ -220,21 +214,26 @@ def prefill_bundle(run_cfg: RunConfig, mesh: Optional[Mesh] = None) -> StepBundl
     return StepBundle(fn, (params_sds, batch_sds), in_sh, out_sh)
 
 
-def is_kv_leaf(path, leaf) -> bool:
-    """A rank-5 KV cache leaf, keyed ``k`` or ``v`` at any depth."""
-    key = getattr(path[-1], "key", None) if path else None
-    return key in ("k", "v") and getattr(leaf, "ndim", 0) == 5
+def position_axes(cache_axes: Any) -> Any:
+    """Which cache leaves grow with position, as the model declares them:
+    for each leaf of the logical-axes tree that ``decode_state_specs`` gives,
+    the index of its ``kv_seq`` axis, or ``None`` where it names none.  A
+    leaf that grows is padded to the decode length along that axis and takes
+    the layout the compiler chooses for the decode loop."""
+    return jax.tree.map(lambda axes: axes.index("kv_seq") if "kv_seq" in axes else None,
+                        cache_axes, is_leaf=lambda x: isinstance(x, tuple))
 
 
 def decode_bundle(run_cfg: RunConfig, mesh: Optional[Mesh] = None) -> StepBundle:
     """One-token serve_step against a full-length cache (decode_* shapes).
 
-    The cache's K/V leaves take the layout the compiler chooses for the
-    decode loop (``Layout.AUTO``) on input and output, so that a step's
-    cache feeds the next step as it is, with no relayout between steps.  A
-    concrete array can only be passed to the step once it is compiled:
-    ``lower(...).compile()``, then ``Compiled.input_formats`` says the
-    layout to put the cache in (``Server`` does this once per shape).
+    The cache's leaves that grow with position (``position_axes``) take the
+    layout the compiler chooses for the decode loop (``Layout.AUTO``) on
+    input and output, so that a step's cache feeds the next step as it is,
+    with no relayout between steps.  A concrete array can only be passed
+    to the step once it is compiled: ``lower(...).compile()``, then
+    ``Compiled.input_formats`` says the layout to put the cache in
+    (``Server`` does this once per shape).
     """
     model = build_model(run_cfg.model, run_cfg.sharding)
     rules = _rules(run_cfg, model)
@@ -242,7 +241,7 @@ def decode_bundle(run_cfg: RunConfig, mesh: Optional[Mesh] = None) -> StepBundle
     def decode_step(params, cache, batch):
         return model.decode_step(params, cache, batch)
 
-    fn = _ambient(decode_step, rules, mesh, run_cfg.sharding)
+    fn = _ambient(decode_step, rules, mesh)
     params_sds = model.abstract()
     cache_sds, cache_axes, tok_sds, tok_axes = model.decode_state_specs(run_cfg.shape)
     params_sh = cache_sh = tok_sh = logits_sh = None
@@ -258,9 +257,9 @@ def decode_bundle(run_cfg: RunConfig, mesh: Optional[Mesh] = None) -> StepBundle
             lambda a: NamedSharding(mesh, axes_to_pspec(a, rules)), tok_axes,
             is_leaf=lambda x: isinstance(x, tuple))
         logits_sh = NamedSharding(mesh, axes_to_pspec(("batch", None, "vocab"), rules))
-    cache_fmt = jax.tree_util.tree_map_with_path(
-        lambda path, sds, sh: Format(Layout.AUTO, sh) if is_kv_leaf(path, sds) else sh,
-        cache_sds, cache_sh, is_leaf=lambda x: x is None)
+    cache_fmt = jax.tree.map(
+        lambda axis, sh: sh if axis is None else Format(Layout.AUTO, sh),
+        position_axes(cache_axes), cache_sh, is_leaf=lambda x: x is None)
     return StepBundle(fn, (params_sds, cache_sds, tok_sds),
                       (params_sh, cache_fmt, tok_sh), (logits_sh, cache_fmt),
                       donate_argnums=(1,))

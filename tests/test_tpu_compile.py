@@ -1,5 +1,6 @@
-"""Compile the main path's kernels, the qwen1.5-4b decode step and the
-rwkv6-1.6b serve steps for a described TPU v5e, with no chip attached.
+"""Compile the main path's kernels, the qwen1.5-4b decode step, the
+rwkv6-1.6b serve steps and a zamba2-7b decode step for a described TPU v5e,
+with no chip attached.
 
 The TPU compiler refuses what interpret mode accepts (unaligned slices, too
 much VMEM, dots with several batch dimensions) and programs that do not fit
@@ -176,6 +177,62 @@ def test_rwkv_decode_holds_its_state_in_place(rwkv_serve_step):
     _, cache_out = compiled.output_formats
     assert cache_in["state"] == cache_out["state"]
     assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2**20
+
+
+def test_hybrid_decode_holds_its_state_in_place(one_chip):
+    """zamba2-7b's decode step at smoke widths and batch 8, with two groups
+    of three mamba layers and a tail of two, compiled for a described v5e:
+    the shared attention's stacked K/V and the stacked mamba ``state`` and
+    ``conv`` are updated where they lie.  No op outside a fusion makes a
+    layer's state or K/V slice or a group's slice of a stack; an op that
+    makes a whole stack is the loop, the compiler's move of it between
+    memories or an in-place update (a fusion whose root is a
+    dynamic-update-slice), never a copy; each cache leaf keeps one format
+    from input to output.  A layer's conv slice is read out once a loop:
+    the window's shift reads rows that the in-place write overwrites."""
+    cfg = C.get("zamba2-7b").smoke.replace(num_layers=8, attn_every=3)
+    rc = C.RunConfig(model=cfg, shape=C.ShapeConfig("serve", 64, 8, "decode"),
+                     mesh=C.SMOKE_MESH)
+    bundle = decode_bundle(rc)
+    args = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip),
+                        bundle.abstract_inputs)
+    compiled = bundle.jit().lower(*args).compile()
+    mod = _parse_tpu_hlo(compiled.as_text())
+    groups, tail = bundle.abstract_inputs[1]["groups"], bundle.abstract_inputs[1]["tail"]
+
+    def layer(shape, lead):
+        """A layer's slice of a stack with ``lead`` layer axes, its layer
+        axes dropped or kept at size 1."""
+        return {shape[lead:], (1,) + shape[lead:], (1, 1) + shape[lead:]}
+
+    stacks = {x.shape for x in jax.tree.leaves((groups, tail))}
+    slices = (layer(groups["k"].shape, 1) | layer(groups["mamba"]["state"].shape, 2)
+              | layer(tail["state"].shape, 1)
+              | {shape[1:] for shape in (groups["mamba"]["state"].shape,
+                                         groups["mamba"]["conv"].shape)}
+              | {(1,) + shape[1:] for shape in (groups["mamba"]["state"].shape,
+                                                groups["mamba"]["conv"].shape)})
+    conv_reads = layer(groups["mamba"]["conv"].shape, 2) | layer(tail["conv"].shape, 1)
+
+    def in_place(op):
+        if op.opcode != "fusion":
+            return False
+        called = mod.comp(re.search(r"calls=%?([\w.\-]+)", op.raw).group(1))
+        return called.by_name[called.root].opcode == "dynamic-update-slice"
+
+    made = [(op, tuple(s.dims)) for comp in _top_level(mod).values() for op in comp.ops
+            if op.opcode not in ("parameter", "get-tuple-element", "tuple")
+            for s in op.outputs]
+    sliced = [op.name for op, dims in made if dims in slices]
+    assert not sliced, f"ops that make a layer's or a group's slice: {sliced}"
+    whole = [op.name for op, dims in made if dims in stacks and not in_place(op)
+             and op.opcode not in ("while", "copy-start", "copy-done")]
+    assert not whole, f"ops that make a whole stack anew: {whole}"
+    reads = [op.name for op, dims in made if dims in conv_reads]
+    assert len(reads) <= 2, f"conv slices read out: {reads}"
+    (_, cache_in, _), _ = compiled.input_formats
+    _, cache_out = compiled.output_formats
+    assert cache_in == cache_out
 
 
 def test_lenet_train_step_compiles(topo):
